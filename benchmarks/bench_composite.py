@@ -8,48 +8,22 @@ true-residual outer steps, tier promotion on stagnation). Records solve
 time, iteration counts (must not drift with the shard count), the
 sub-32-bit matvec fraction, and the dist-mixed vs dist-fp32 speedup.
 
-JAX fixes the device count at backend initialization, so ``run``
-re-executes this module in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and folds the
-child's rows back into the shared results (same recipe as
-``bench_distributed``; DESIGN.md §2.5's relative-instrument caveat applies
-doubly on simulated devices).
+Runs in the calling process over the devices it sees, as
+``bench_distributed`` does (on the CPU, 8 host devices via
+``common.cpu_host_devices``; DESIGN.md §2.5's relative-instrument caveat
+applies doubly on simulated devices).
 
 Writes ``BENCH_composite.json`` at the repo root.
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 
 N_DEV = 8
 SHARD_COUNTS = (2, 4, 8)
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _JSON_PATH = os.environ.get("REPRO_BENCH_COMPOSITE_JSON",
                             os.path.join(_ROOT, "BENCH_composite.json"))
-
-
-def run(scale: str | None = None) -> None:
-    """Parent entry point (benchmarks.run): spawn the forced-device-count
-    child, then re-ingest its rows."""
-    from . import common
-    scale = scale or common.SCALE
-    env = os.environ.copy()
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={N_DEV}"
-                        ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_composite",
-         "--scale", scale],
-        env=env, cwd=_ROOT)
-    if proc.returncode != 0:
-        raise RuntimeError(f"bench_composite child failed "
-                           f"(exit {proc.returncode})")
-    with open(_JSON_PATH) as f:
-        payload = json.load(f)
-    common.rows().extend(payload["rows"])
 
 
 def _suite(scale: str):
@@ -61,7 +35,7 @@ def _suite(scale: str):
     return testmats.hpcg(16, 16, 16), (1e-8, 60, 16)      # medium
 
 
-def _child(scale: str) -> None:
+def run(scale: str | None = None) -> None:
     import jax
     jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
@@ -72,6 +46,8 @@ def _child(scale: str) -> None:
 
     from . import common
 
+    scale = scale or common.SCALE
+    first_row = len(common.rows())
     ndev = jax.device_count()
     a, (tol, maxiter, m_in) = _suite(scale)
     s, _ = op.sym_scale(a)
@@ -129,7 +105,7 @@ def _child(scale: str) -> None:
               "measure dispatch + word-stream-volume effects, not real "
               "interconnect bandwidth; iteration counts are the invariant "
               "to watch (must not drift with P)"),
-        rows=common.rows(),
+        rows=common.rows()[first_row:],
     )
     common.save_bench_json(_JSON_PATH, payload)
 
@@ -139,4 +115,6 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", default=None)
     args = ap.parse_args()
-    _child(args.scale or os.environ.get("REPRO_BENCH_SCALE", "small"))
+    from . import common
+    common.cpu_host_devices(N_DEV)
+    run(args.scale)

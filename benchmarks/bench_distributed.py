@@ -5,11 +5,11 @@ scaling: per-shard problem size held constant while the fleet grows. Both
 sweep the two halo-exchange modes and record the distributed Jacobi-PCG
 (time, iterations — iteration counts must not drift with the shard count).
 
-JAX fixes the device count at backend initialization, so ``run`` re-executes
-this module in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and folds the child's
-rows back into the shared results. Simulated host devices share one CPU:
-the curves measure dispatch + partition overheads and communication-volume
+Runs in the calling process over the devices it sees; shard counts above
+the device count are skipped. On the CPU (``JAX_PLATFORMS=cpu``) the entry
+points give the process 8 host devices before JAX starts
+(``common.cpu_host_devices``). Simulated host devices share one CPU: the
+curves measure dispatch + partition overheads and communication-volume
 effects, not real interconnect bandwidth (DESIGN.md §2.5's relative-
 instrument caveat applies doubly here).
 
@@ -18,37 +18,13 @@ Writes ``BENCH_distributed.json`` at the repo root, next to
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 
 N_DEV = 8
 SHARD_COUNTS = (1, 2, 4, 8)
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _JSON_PATH = os.environ.get("REPRO_BENCH_DIST_JSON",
                             os.path.join(_ROOT, "BENCH_distributed.json"))
-
-
-def run(scale: str | None = None) -> None:
-    """Parent entry point (benchmarks.run): spawn the forced-device-count
-    child, then re-ingest its rows."""
-    from . import common
-    scale = scale or common.SCALE
-    env = os.environ.copy()
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={N_DEV}"
-                        ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_distributed",
-         "--scale", scale],
-        env=env, cwd=_ROOT)
-    if proc.returncode != 0:
-        raise RuntimeError(f"bench_distributed child failed "
-                           f"(exit {proc.returncode})")
-    with open(_JSON_PATH) as f:
-        payload = json.load(f)
-    common.rows().extend(payload["rows"])
 
 
 def _suite(scale: str):
@@ -60,7 +36,7 @@ def _suite(scale: str):
     return testmats.hpcg(24, 24, 24), 16, (1e-6, 200)     # medium
 
 
-def _child(scale: str) -> None:
+def run(scale: str | None = None) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -72,6 +48,8 @@ def _child(scale: str) -> None:
 
     from . import common
 
+    scale = scale or common.SCALE
+    first_row = len(common.rows())
     ndev = jax.device_count()
     a_strong, weak_side, (tol, maxiter) = _suite(scale)
     s_strong, _ = op.sym_scale(a_strong)
@@ -137,7 +115,7 @@ def _child(scale: str) -> None:
         note=("simulated host devices share one CPU: curves measure "
               "dispatch/partition overhead and communication volume, not "
               "interconnect bandwidth; speedup_vs_p1 = t(P=1)/t(P)"),
-        rows=common.rows(),
+        rows=common.rows()[first_row:],
     )
     common.save_bench_json(_JSON_PATH, payload)
 
@@ -147,5 +125,6 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", default=None)
     args = ap.parse_args()
-    scale = args.scale or os.environ.get("REPRO_BENCH_SCALE", "small")
-    _child(scale)
+    from . import common
+    common.cpu_host_devices(N_DEV)
+    run(args.scale)

@@ -23,8 +23,9 @@ scores it against three byte models:
   byte model).
 
 The peak-bandwidth denominator comes from
-:func:`repro.launch.roofline.peak_bandwidth`: a hardware constant on
-TPU/GPU, a measured STREAM-triad probe on CPU (source string recorded).
+:func:`repro.launch.roofline.peak_bandwidth`: the published HBM peak of
+the device kind. A device with no published peak (the CPU backend) gets
+no fraction: ``achieved_frac_of_peak`` is ``None`` ("not measured").
 
 The run executes with the flight recorder enabled and embeds
 ``repro.observe.report()`` in the payload, so the dispatch counters /
@@ -100,7 +101,8 @@ def _span_profile(plan, mat, x, hlo_txt: str) -> dict:
     return d
 
 
-def _cells(name: str, a, peak: dict, profile: bool = False) -> list[dict]:
+def _cells(name: str, a, peak: dict | None,
+           profile: bool = False) -> list[dict]:
     """One scoreboard row per codec for matrix class ``name`` — both
     codecs timed interleaved so the fp16-vs-packed ratio is paired."""
     a = a.tocsr()
@@ -148,7 +150,7 @@ def _cells(name: str, a, peak: dict, profile: bool = False) -> list[dict]:
         hlo = _hlo_bytes(hlo_txt)
 
         gbs = stream_bytes / t / 1e9
-        frac = gbs * 1e9 / peak["bw_bytes_per_s"]
+        frac = None if peak is None else gbs * 1e9 / peak["bw_bytes_per_s"]
         ratio = hlo / max(stream_bytes, 1)
         row = dict(
             klass=name, codec=codec, D=D, n=mat.n, nnz=int(mat.nnz),
@@ -162,7 +164,7 @@ def _cells(name: str, a, peak: dict, profile: bool = False) -> list[dict]:
             hlo_vs_model_ratio=ratio,
             hlo_within_tolerance=bool(ratio <= HLO_TOLERANCE),
             measured_gbs=gbs,
-            peak_gbs=peak["bw_bytes_per_s"] / 1e9,
+            peak_gbs=None if peak is None else peak["bw_bytes_per_s"] / 1e9,
             achieved_frac_of_peak=frac,
             variant_pallas=plans_pl[key].variant,
             t_spmv_pallas_s=(float(np.median(ts_pl[key]))
@@ -221,10 +223,15 @@ def run(scale: str | None = None, profile: bool | None = None) -> None:
             "0", "", "false")
     prev = observe.enable(True)          # the run records itself
     try:
-        peak = rl.peak_bandwidth()
-        common.emit("roofline_peak", peak["backend"],
-                    peak_gbs=peak["bw_bytes_per_s"] / 1e9,
-                    source=peak["source"])
+        dev = jax.devices()[0]
+        # the CPU has no published peak; an accelerator missing from the
+        # table raises
+        peak = (None if dev.platform == "cpu"
+                else rl.peak_bandwidth(dev.device_kind))
+        if peak is not None:
+            common.emit("roofline_peak", dev.device_kind,
+                        peak_gbs=peak["bw_bytes_per_s"] / 1e9,
+                        source=peak["source"])
         cells = []
         for name, a in testmats.suite("tiny").items():
             cells.extend(_cells(name, a, peak, profile=profile))
@@ -244,8 +251,8 @@ def run(scale: str | None = None, profile: bool | None = None) -> None:
                   "(includes decode intermediates, so ratio > 1 is "
                   "expected; > hlo_tolerance is flagged); "
                   "achieved_frac_of_peak divides the stream-model GB/s by "
-                  "peak_bandwidth (hardware constant on TPU/GPU, STREAM "
-                  "probe on CPU)"),
+                  "peak_bandwidth, the published HBM peak of the device "
+                  "kind (null where none is published)"),
             cells=cells,
             observe_report=observe.report(),
             legacy_dryrun=_legacy_dryrun_cells(),

@@ -23,6 +23,20 @@ BENCH_SCHEMA_VERSION = 1
 _ROWS: list[dict] = []
 
 
+def cpu_host_devices(n: int) -> None:
+    """CPU rehearsal of the multi-device benchmarks: with
+    ``JAX_PLATFORMS=cpu``, give this process ``n`` host devices. XLA reads
+    the flag when the backend starts, so entry points call this before
+    anything touches a device. Anywhere else (a chip host) it does nothing:
+    the benchmarks run over the devices there are, in this one process."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}").strip()
+
+
 def bench_meta(**extra) -> dict:
     """Schema-versioned metadata header stamped into every BENCH_*.json.
     Provenance fields (commit, toolchain, machine) come from

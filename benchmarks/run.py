@@ -3,7 +3,9 @@
     PYTHONPATH=src python -m benchmarks.run [--only spmv,e8my] [--scale small]
 
 Output: CSV lines ``bench,case,k=v,...`` plus artifacts/bench_results.json.
-Scales: tiny (CI), small (default), medium.
+Scales: tiny (CI), small (default), medium. Every module runs in this one
+process; with ``JAX_PLATFORMS=cpu`` the multi-device modules get 8 host
+devices (:func:`common.cpu_host_devices`).
 """
 from __future__ import annotations
 
@@ -11,10 +13,13 @@ import argparse
 import sys
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 from . import common
 
 MODULES = ("spmv", "memory", "e8my", "f3r", "iocg", "kernels", "roofline",
            "distributed", "precision", "composite", "robust", "serving")
+MULTI_DEVICE = ("distributed", "composite")
 
 
 def main() -> None:
@@ -25,6 +30,9 @@ def main() -> None:
     ap.add_argument("--out", default="artifacts/bench_results.json")
     args = ap.parse_args()
     only = args.only.split(",") if args.only else list(MODULES)
+    if set(only) & set(MULTI_DEVICE):
+        common.cpu_host_devices(8)
+    use_compile_cache()
 
     t0 = time.time()
     failures = []

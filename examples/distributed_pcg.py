@@ -24,11 +24,13 @@ jax.config.update("jax_enable_x64", True)
 
 from repro.core import packsell, testmats                     # noqa: E402
 from repro.distributed import build_dist_plan                 # noqa: E402
+from repro.launch.compile_cache import use_compile_cache     # noqa: E402
 from repro.solvers import cg                                  # noqa: E402
 from repro.solvers import operators as op                     # noqa: E402
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--side", type=int, default=10,
                     help="HPCG grid side (n = side^3 rows)")

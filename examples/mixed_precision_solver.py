@@ -17,6 +17,7 @@ import numpy as np
 jax.config.update("jax_enable_x64", True)
 
 from repro.core import testmats                             # noqa: E402
+from repro.launch.compile_cache import use_compile_cache    # noqa: E402
 from repro.solvers import cg, f3r, iocg                     # noqa: E402
 from repro.solvers.operators import OperatorSet, sym_scale  # noqa: E402
 
@@ -28,6 +29,7 @@ def true_relres(a, x, b):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nx", type=int, default=10)
     ap.add_argument("--budget", type=float, default=1e-3,

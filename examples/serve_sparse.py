@@ -8,12 +8,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as tfm
 from repro.models.sparse_linear import PackSELLLinear
 from repro.serving import DecodeEngine, ServeConfig
 
 
 def main():
+    use_compile_cache()
     cfg = configs.reduce(configs.get("granite-3-2b"))
     params, _ = tfm.init_params(cfg, jax.random.PRNGKey(0))
 

@@ -19,6 +19,7 @@ import shutil
 import jax
 
 from repro import configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.config import ModelConfig
 from repro.optim import OptConfig
 from repro.train import Trainer, TrainerConfig
@@ -42,6 +43,7 @@ PRESETS = {
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="smoke", choices=list(PRESETS))
     ap.add_argument("--steps", type=int, default=None)

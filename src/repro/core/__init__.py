@@ -5,7 +5,7 @@ Public API::
     from repro.core import packsell, sell, sparse, codecs, testmats
     A = packsell.from_csr(csr, C=128, sigma=256, D=15, codec="fp16")
     y = A.spmv(x)                        # vectorized jnp path
-    y = kernels.ops.packsell_spmv(A, x)  # Pallas TPU kernel path
+    y = kernels.ops.packsell_spmv(A, x)  # cached plan engine
 """
 from . import (codecs, delta, packsell, reorder, sell, sparse,  # noqa: F401
                testmats, trisolve)

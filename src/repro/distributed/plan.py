@@ -54,7 +54,7 @@ from repro.core import sell as sl
 from repro.kernels import composite as kc
 from repro.kernels import plan as kplan
 from repro.observe import metrics as _obs
-from repro.parallel.sharding import make_shard_mesh, shard_map_compat
+from repro.parallel.sharding import make_shard_mesh, shard_map_unchecked
 
 from . import halo as dh
 from . import partition as dp
@@ -549,9 +549,9 @@ class DistSpMVPlan(_MeshBound):
                                         multi_rhs=multi_rhs)
                 return y[None]
 
-            f = shard_map_compat(body, self.mesh,
-                                 in_specs=(self.dev_specs, P(ax)),
-                                 out_specs=P(ax))
+            f = shard_map_unchecked(body, self.mesh,
+                                    in_specs=(self.dev_specs, P(ax)),
+                                    out_specs=P(ax))
             return jax.jit(f)
 
         return self.cached_fn(("spmm" if multi_rhs else "spmv", mode), build)

@@ -12,7 +12,9 @@ Variant policy is explicit (logged in ``plan.policy``) and overridable via
 
 On non-TPU backends the Pallas kernels execute with ``interpret=True``
 (kernel body evaluated in Python/XLA on CPU) — numerically identical, used by
-the test suite to validate against the pure-jnp oracles in ``ref.py``.
+the test suite to validate against the pure-jnp oracles in ``ref.py``. On a
+TPU no Pallas body compiles yet (``plan.PALLAS_REFUSAL``), so ``auto`` runs
+the XLA path there.
 """
 from __future__ import annotations
 
@@ -31,10 +33,6 @@ from . import sell_spmv as _sk
 band_plan = _plan.band_plan
 _FULL_X_LIMIT = _plan._FULL_X_LIMIT
 _DEF_HW = _plan._DEF_HW
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _debug_check_finite(x) -> None:
@@ -91,7 +89,7 @@ def packsell_spmm(mat: PackSELLMatrix, x: jnp.ndarray, *, sb: int = 8,
 
 def sell_spmv(mat: SELLMatrix, x: jnp.ndarray, *, sb: int = 8, wb: int = 32,
               interpret: bool | None = None) -> jnp.ndarray:
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _plan._interpret_default() if interpret is None else interpret
     parts = []
     for val, col in zip(mat.vals, mat.cols):
         t = _sk.sell_spmv_bucket(val, col, x, sb=sb, wb=wb,

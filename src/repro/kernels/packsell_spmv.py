@@ -52,7 +52,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import codecs as cd
-from . import compat
 
 
 def _unpack(words: jnp.ndarray, codec: cd.Codec, D: int):
@@ -165,7 +164,8 @@ def packsell_spmv_bucket(pack: jnp.ndarray, d0: jnp.ndarray, x: jnp.ndarray,
             ],
             out_specs=pl.BlockSpec((1, sb, C), lambda si, wi: (wi, si, 0)),
             out_shape=jax.ShapeDtypeStruct((nw, Sp, C), jnp.float32),
-            compiler_params=compat.compiler_params("parallel", "parallel"),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
             name=f"packsell_spmv_ckpt_{codec_name}_D{D}",
         )(_pad_ckpt(ckpt, s_pad), pack, xp)
@@ -185,7 +185,8 @@ def packsell_spmv_bucket(pack: jnp.ndarray, d0: jnp.ndarray, x: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((Sp, C), jnp.float32),
         scratch_shapes=[pltpu.VMEM((sb, C), jnp.int32),
                         pltpu.VMEM((sb, C), jnp.float32)],
-        compiler_params=compat.compiler_params("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=f"packsell_spmv_{codec_name}_D{D}",
     )(d0, pack, xp)
@@ -312,7 +313,8 @@ def packsell_spmv_band_bucket(pack: jnp.ndarray, d0: jnp.ndarray,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((nw, Sp, C), jnp.float32),
-            compiler_params=compat.compiler_params("parallel", "parallel"),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
             name=f"packsell_spmv_band_ckpt_{codec_name}_D{D}",
         )(win, _pad_ckpt(ckpt, s_pad), pack, xp, xp)
@@ -337,7 +339,8 @@ def packsell_spmv_band_bucket(pack: jnp.ndarray, d0: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Sp, C), jnp.float32),
-        compiler_params=compat.compiler_params("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=f"packsell_spmv_band_{codec_name}_D{D}",
     )(win, d0, pack, xp, xp)
@@ -450,7 +453,8 @@ def packsell_spmm_bucket(pack: jnp.ndarray, d0: jnp.ndarray, x: jnp.ndarray,
             out_specs=pl.BlockSpec((1, sb, C, nbp),
                                    lambda si, wi: (wi, si, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((nw, Sp, C, nbp), jnp.float32),
-            compiler_params=compat.compiler_params("parallel", "parallel"),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
             name=f"packsell_spmm_ckpt_{codec_name}_D{D}",
         )(_pad_ckpt(ckpt, s_pad), pack, xp)
@@ -471,7 +475,8 @@ def packsell_spmm_bucket(pack: jnp.ndarray, d0: jnp.ndarray, x: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((Sp, C, nbp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((sb, C), jnp.int32),
                         pltpu.VMEM((sb, C, nbp), jnp.float32)],
-        compiler_params=compat.compiler_params("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=f"packsell_spmm_{codec_name}_D{D}",
     )(d0, pack, xp)
@@ -610,7 +615,8 @@ def packsell_spmv_fused(words3d: jnp.ndarray, ckpt: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, gb, C), lambda gi, wi: (wi, gi, 0)),
         out_shape=jax.ShapeDtypeStruct((nwk, Gp, C), jnp.float32),
-        compiler_params=compat.compiler_params("parallel", "parallel"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name=f"packsell_spmv_fused_{encoding}_{codec_name}_D{D}",
     )(ckpt, words3d, xp)
@@ -657,7 +663,8 @@ def packsell_spmm_fused(words3d: jnp.ndarray, ckpt: jnp.ndarray,
         out_specs=pl.BlockSpec((1, gb, C, nbp),
                                lambda gi, wi: (wi, gi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nwk, Gp, C, nbp), jnp.float32),
-        compiler_params=compat.compiler_params("parallel", "parallel"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name=f"packsell_spmm_fused_{encoding}_{codec_name}_D{D}",
     )(ckpt, words3d, xp)

@@ -42,20 +42,22 @@ module moves every host-side decision out of the hot path:
   chain (``packsell_spmv.py``).
 * Variant selection is explicit and logged (:attr:`SpMVPlan.policy`):
 
+  - ``'jnp'``   — the fused-stream / scan-decode XLA path: the ``auto``
+    choice on every backend. On a TPU no Pallas body compiles
+    (:data:`PALLAS_REFUSAL`); elsewhere the Pallas kernels only run in
+    interpret mode.
   - ``'fused'`` — the fused-stream Pallas kernel
     (``packsell_spmv.packsell_spmv_fused``): ONE kernel over the whole
     repacked ``uint32[G, wr, C]`` word stream + ``int32[G, C]``
-    checkpoints, grid parallel over group × word-run tiles (no per-bucket
-    dispatch, no cursor carry). The auto default on compiled backends
-    when the stream is feasible and x fits VMEM residency.
+    checkpoints, grid parallel over group × word-run tiles.
   - ``'band'``  — band-windowed per-bucket Pallas kernel (bounded VMEM;
     RCM/banded regime),
-  - ``'full'``  — full-x-in-VMEM per-bucket Pallas kernel,
-  - ``'jnp'``   — the fused-stream / scan-decode XLA path (the fast path on
-    non-TPU backends, where the Pallas kernels only run in interpret mode).
+  - ``'full'``  — full-x-in-VMEM per-bucket Pallas kernel.
 
   The automatic choice can be overridden per call (``force=``) or globally
-  via the ``REPRO_SPMV_POLICY`` env var (``auto|fused|full|band|jnp``).
+  via the ``REPRO_SPMV_POLICY`` env var (``auto|fused|full|band|jnp``). The
+  Pallas variants are interpret-mode only: forcing one with
+  ``interpret=False`` raises at plan build, quoting Mosaic's refusal.
 """
 from __future__ import annotations
 
@@ -78,9 +80,22 @@ from . import packsell_spmv as _pk
 
 _DEF_HW = 4096              # default half-window (elements, multiple of 128)
 _FULL_X_LIMIT = int(os.environ.get("REPRO_FULL_X_LIMIT", 2_000_000))
-_BAND_MIN_M = int(os.environ.get("REPRO_BAND_MIN_M", 65_536))
 
 _POLICIES = ("auto", "full", "band", "jnp", "fused")
+_PALLAS_VARIANTS = ("full", "band", "fused")
+
+#: Why no Pallas variant runs compiled: Mosaic, the Pallas TPU lowering of
+#: the installed JAX (0.9.0), refuses every PackSELL kernel body.
+#: ``tests/test_tpu_compile.py`` compiles them for a described v5e and pins
+#: these refusals.
+PALLAS_REFUSAL = (
+    "Mosaic refuses every PackSELL Pallas body: the fused kernels with "
+    "'Unimplemented primitive in Pallas TPU lowering for KernelType.TC: "
+    "dynamic_slice' (a per-word slice of the loaded word tile) and, when "
+    "the tile is read from the ref, 'Only 2D gather is supported' (the 1-D "
+    "jnp.take over the whole VMEM-resident x); the bucket kernels on block "
+    "shapes (lane dimension C < 128, rank-1 blocks) and, at C = 128, on the "
+    "same dynamic_slice")
 _CACHE_MODES = ("checkpoint", "full", "0")
 
 
@@ -158,6 +173,8 @@ def _env_cache_mode() -> str:
 
 
 def _interpret_default() -> bool:
+    """Pallas kernels run in interpret mode off a TPU. Plans record the
+    flag (:attr:`SpMVPlan.interpret`), so a run can check it."""
     return jax.default_backend() != "tpu"
 
 
@@ -1182,6 +1199,13 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
     n_buckets = len(mat.packs)
     tiles = tuple((sb, wb) for _ in range(n_buckets))
 
+    src = f"force={force!r}" if force else "REPRO_SPMV_POLICY"
+    if not interpret and policy in _PALLAS_VARIANTS:
+        raise ValueError(
+            f"{src}: the {policy!r} Pallas variant does not compile for a "
+            f"TPU — {PALLAS_REFUSAL}; use force='jnp' (the 'auto' choice) "
+            "or interpret=True")
+
     if _is_traced(mat):
         # Under jit tracing the host cannot inspect column metadata: band
         # feasibility is undecidable and the decode caches cannot be built,
@@ -1206,38 +1230,21 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
             ephemeral=True)
 
     wins = None
-    if policy in ("auto", "band") and mat.m > 0:
-        wins = band_plan(mat, sb, hw)
-
-    # Probe fused-stream feasibility up front where the fused Pallas
-    # variant is in play: forced, or the auto default on compiled backends
-    # (the kernel gathers the whole x, so the full-x residency limit
-    # applies to it like the 'full' bucket kernel).
     fused, layout, orders = (None, None, None)
-    want_fused = (policy == "fused"
-                  or (policy == "auto" and not interpret
-                      and mat.m <= _FULL_X_LIMIT))
-    if want_fused:
-        fused, layout, orders = _build_fused_stream(mat, trim=fused_trim,
-                                                    wr=ckpt_wr)
-
     if policy == "band":
+        wins = band_plan(mat, sb, hw) if mat.m > 0 else None
         if wins is None:
             raise ValueError("band kernel infeasible for this matrix/hw")
-        variant, reason = "band", "forced via " + (
-            f"force={force!r}" if force else "REPRO_SPMV_POLICY")
-    elif policy == "full":
-        variant, reason = "full", "forced via " + (
-            f"force={force!r}" if force else "REPRO_SPMV_POLICY")
-    elif policy == "jnp":
-        variant, reason = "jnp", "forced via " + (
-            f"force={force!r}" if force else "REPRO_SPMV_POLICY")
+        variant, reason = "band", f"forced via {src}"
+    elif policy in ("full", "jnp"):
+        variant, reason = policy, f"forced via {src}"
     elif policy == "fused":
         if mat.m > _FULL_X_LIMIT:
             raise ValueError(
                 f"x too large for VMEM residency (m={mat.m}); the fused "
                 "kernel gathers the whole x — use band/jnp")
-        src = f"force={force!r}" if force else "REPRO_SPMV_POLICY"
+        fused, layout, orders = _build_fused_stream(mat, trim=fused_trim,
+                                                    wr=ckpt_wr)
         if fused is None:
             # forced fused but no compact encoding fits: demote to the
             # jnp variant on the full cursor cache, loudly
@@ -1248,47 +1255,18 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
             mode = "full"
         else:
             variant, reason = "fused", f"forced via {src}"
-    else:  # auto
-        if interpret:
-            variant = "jnp"
-            reason = ("auto: non-TPU backend — Pallas (incl. the fused-"
-                      "stream kernel) would run in interpret mode, fused-"
-                      "stream XLA path is faster (force='fused' runs the "
-                      "interpret kernel anyway)")
-        elif fused is not None:
-            variant = "fused"
-            reason = (f"auto: compiled backend, fused stream feasible and "
-                      f"m={mat.m} fits VMEM residency — fused-stream "
-                      "Pallas kernel")
-        elif wins is not None and mat.m >= _BAND_MIN_M:
-            variant = "band"
-            reason = (f"auto: band feasible and m={mat.m} >= "
-                      f"REPRO_BAND_MIN_M={_BAND_MIN_M} (bounds VMEM)"
-                      + ("; fused stream infeasible (span overflow)"
-                         if want_fused else ""))
-        elif mat.m <= _FULL_X_LIMIT:
-            variant = "full"
-            reason = (f"auto: m={mat.m} fits VMEM residency"
-                      + ("; fused stream infeasible (span overflow)"
-                         if want_fused else "")
-                      + ("" if wins is None else
-                         f" (band feasible but m < REPRO_BAND_MIN_M="
-                         f"{_BAND_MIN_M}: window bookkeeping not worth it)"))
-        elif wins is not None:
-            variant = "band"
-            reason = f"auto: m={mat.m} > REPRO_FULL_X_LIMIT={_FULL_X_LIMIT}"
-        else:
-            raise ValueError(
-                f"x too large for VMEM residency (m={mat.m}) and band "
-                f"kernel infeasible; increase hw or force='jnp'")
+    elif interpret:
+        variant = "jnp"
+        reason = ("auto: non-TPU backend — Pallas (incl. the fused-"
+                  "stream kernel) would run in interpret mode, fused-"
+                  "stream XLA path is faster (force='fused' runs the "
+                  "interpret kernel anyway)")
+    else:
+        variant = "jnp"
+        reason = f"auto: compiled TPU backend — {PALLAS_REFUSAL}"
     if variant == "full" and mat.m > _FULL_X_LIMIT:
         raise ValueError(
             f"x too large for VMEM residency (m={mat.m}); use band/jnp")
-    if variant != "band":
-        wins = None
-    if variant != "fused" and policy != "fused":
-        # a probe-built stream the selected variant will not consume
-        fused, layout, orders = (None, None, None)
 
     cols = None
     kckpts = None

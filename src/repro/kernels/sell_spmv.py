@@ -14,8 +14,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 
 def _kernel(val_ref, col_ref, x_ref, y_ref, acc_ref, *, nw: int, wb: int):
     wi = pl.program_id(1)
@@ -71,7 +69,8 @@ def sell_spmv_bucket(val: jnp.ndarray, col: jnp.ndarray, x: jnp.ndarray, *,
         out_specs=pl.BlockSpec((sb, C), lambda si, wi: (si, 0)),
         out_shape=jax.ShapeDtypeStruct((Sp, C), jnp.float32),
         scratch_shapes=[pltpu.VMEM((sb, C), jnp.float32)],
-        compiler_params=compat.compiler_params("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="sell_spmv",
     )(val, col, xp)

@@ -24,68 +24,32 @@ HW = {
     "ici_bw": 50e9,              # per link (single-link model)
 }
 
-# Backend-detected peak-bandwidth constants for the achieved-vs-peak
-# scoreboard (benchmarks/bench_roofline.py). TPU: the v5e HBM constant
-# above; GPU: a nominal HBM2e figure (A100-class — the scoreboard reports
-# the source string so cross-machine comparisons stay honest). CPU has no
-# meaningful nominal constant: peak_bandwidth() falls back to a measured
-# STREAM-triad probe.
-_PEAK_BW_CONSTANTS = {
-    "tpu": ("constant:tpu_v5e_hbm", 819e9),
-    "gpu": ("constant:gpu_hbm2e_nominal", 900e9),
+#: Published per-chip HBM bandwidth, keyed by ``jax.Device.device_kind``
+#: (the string JAX reports). Source: Google Cloud documentation, "TPU v5e"
+#: — 16 GB of HBM at 819 GB/s per chip. A device that is not in the table
+#: has no peak: :func:`peak_bandwidth` raises rather than guess one.
+PEAK_HBM_BW = {
+    "TPU v5 lite": (HW["hbm_bw"], "Google Cloud documentation, TPU v5e"),
 }
-_BW_CACHE: dict = {}
 
 
-def stream_probe_bandwidth(elems: int = 8_000_000,
-                           repeats: int = 7) -> float:
-    """STREAM-triad-style achieved bandwidth (bytes/s) on the current
-    backend: ``a = b + s·c`` over arrays far larger than cache, timed
-    end-to-end (median of ``repeats``), counting 3 × 4 bytes per element
-    (two streamed reads + one write — the classic STREAM convention).
+def peak_bandwidth(device_kind: str | None = None) -> dict:
+    """``{device_kind, bw_bytes_per_s, source}`` — the published HBM peak
+    of ``device_kind`` (default: the first visible device's), the
+    denominator of an achieved-vs-peak fraction. Raises ``KeyError`` for
+    a device with no published peak in :data:`PEAK_HBM_BW`."""
+    if device_kind is None:
+        import jax
 
-    Shared containers get throttle windows lasting whole seconds, long
-    enough to swallow every repeat of a single burst and poison the
-    roofline denominator by an order of magnitude — so the probe runs
-    two separated bursts and keeps the faster median."""
-    import time
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in PEAK_HBM_BW:
+        raise KeyError(
+            f"no published HBM peak for device_kind={device_kind!r}; "
+            f"known: {sorted(PEAK_HBM_BW)}")
+    bw, src = PEAK_HBM_BW[device_kind]
+    return {"device_kind": device_kind, "bw_bytes_per_s": bw,
+            "source": src}
 
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    b = jnp.arange(elems, dtype=jnp.float32)
-    c = jnp.ones((elems,), jnp.float32)
-    f = jax.jit(lambda b, c: b + 0.5 * c)
-    best = 0.0
-    for _ in range(2):
-        jax.block_until_ready(f(b, c))     # compile + warm / re-warm
-        ts = []
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            jax.block_until_ready(f(b, c))
-            ts.append(time.perf_counter() - t0)
-        best = max(best, 3 * 4 * elems / float(np.median(ts)))
-    return best
-
-
-def peak_bandwidth(backend: str | None = None) -> dict:
-    """``{backend, bw_bytes_per_s, source}`` — the denominator of the
-    achieved-vs-peak fraction: a hardware constant on TPU/GPU, a measured
-    STREAM probe elsewhere (CPU containers have no trustworthy nominal
-    figure). Cached per backend — the probe costs ~0.5 s."""
-    import jax
-
-    backend = backend or jax.default_backend()
-    ent = _BW_CACHE.get(backend)
-    if ent is None:
-        if backend in _PEAK_BW_CONSTANTS:
-            src, bw = _PEAK_BW_CONSTANTS[backend]
-        else:
-            src, bw = "stream_probe", stream_probe_bandwidth()
-        ent = _BW_CACHE[backend] = {
-            "backend": backend, "bw_bytes_per_s": float(bw), "source": src}
-    return dict(ent)
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -127,7 +127,7 @@ def make_train_step(cfg: ModelConfig, opt: OptConfig,
         return train_step, specs, zspecs
 
     from repro.optim.compression import compressed_wire_reduce
-    from repro.parallel import current_mesh, shard_map_compat
+    from repro.parallel import current_mesh, shard_map_unchecked
 
     def constrain_tree(tree, spec_tree):
         leaves, treedef = jax.tree.flatten(tree)
@@ -165,10 +165,10 @@ def make_train_step(cfg: ModelConfig, opt: OptConfig,
         mesh = current_mesh()
         rep = jax.tree.map(lambda _: P(), state)
         bspec = jax.tree.map(lambda _: P("pod"), batch)
-        fn = shard_map_compat(pod_body, mesh,
-                              in_specs=(rep, bspec),
-                              out_specs=(rep, {"loss": P()}),
-                              axis_names={"pod"})
+        fn = shard_map_unchecked(pod_body, mesh,
+                                 in_specs=(rep, bspec),
+                                 out_specs=(rep, {"loss": P()}),
+                                 axis_names={"pod"})
         return fn(state, batch)
 
     return train_step_pod, specs, zspecs

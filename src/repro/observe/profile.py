@@ -26,11 +26,13 @@ time for that span.  The whole measured region is bracketed by a marker
 annotation, so ``wall_s`` is the real per-call dispatch wall time,
 including host overhead the device events cannot see.
 
-When the profiler plugin is unavailable (no trace produced, trace API
-raises, or no parseable events), :func:`profile_dispatch` degrades to a
-pure wall-clock measurement with ``profiler_unavailable=True`` — CPU CI
-keeps running, and consumers (``bench_roofline --profile``) surface the
-marker instead of fabricating a breakdown.
+When the profiler plugin is unavailable (no trace produced, or the
+trace API raises) on a non-TPU backend, :func:`profile_dispatch`
+degrades to a pure wall-clock measurement with
+``profiler_unavailable=True`` — CPU CI keeps running, and consumers
+(``bench_roofline --profile``) surface the marker instead of fabricating a
+breakdown. On a TPU a failed trace raises: a wall clock is not a device
+time.
 """
 from __future__ import annotations
 
@@ -217,12 +219,18 @@ def profile_dispatch(fn, *args, spans=SPAN_NAMES, hlo_texts=(),
                         _block(fn(*args))
                 t_wall = time.perf_counter() - t0
         except Exception as e:     # profiler plugin unavailable/busy
+            if backend == "tpu":
+                raise RuntimeError(
+                    f"profile_dispatch: TPU trace failed: {e!r}") from e
             return _wallclock(fn, args, repeats, backend,
                               f"trace failed: {e!r}")
         finally:
             _obs.enable(prev)
         tj = _find_trace_json(td)
         if tj is None:
+            if backend == "tpu":
+                raise RuntimeError(
+                    "profile_dispatch: TPU trace produced no trace.json.gz")
             return _wallclock(fn, args, repeats, backend,
                               "no trace.json.gz produced")
         events = _parse_events(tj)

@@ -52,19 +52,7 @@ def filter_spec(spec: P, mesh: Mesh | None = None) -> P:
 
 def _manual_axes() -> frozenset:
     """Axis names currently under shard_map manual control."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        ma = frozenset(getattr(am, "manual_axes", ()) or ())
-        if ma:
-            return ma
-    except Exception:
-        pass
-    try:
-        # JAX 0.4.x: shard_map binds its mesh axes in the global axis env
-        from jax._src import core as _core
-        return frozenset(_core.get_axis_env().axis_sizes)
-    except Exception:
-        return frozenset()
+    return frozenset(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def _in_manual_context() -> bool:
@@ -99,22 +87,16 @@ def constrain(x, spec: P):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, fs))
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on
-    0.4.x, with replication checking disabled (``check_vma`` /
-    ``check_rep``). ``axis_names`` restricts the manually-mapped axes; on
-    0.4.x it maps to the complementary ``auto=`` set."""
-    if hasattr(jax, "shard_map"):                         # JAX >= 0.6
-        kw = {"check_vma": False}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm   # JAX 0.4.x
-    kw = {"check_rep": False}
+def shard_map_unchecked(f, mesh: Mesh, in_specs, out_specs,
+                        axis_names=None):
+    """``jax.shard_map`` with replication checking disabled
+    (``check_vma=False``). ``axis_names`` restricts the manually-mapped
+    axes."""
+    kw = {"check_vma": False}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def make_shard_mesh(n_shards: int | None = None, *,

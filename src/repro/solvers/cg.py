@@ -213,8 +213,6 @@ def jacobi_pcg_stored(mat, plan, diag: jnp.ndarray, b: jnp.ndarray, *,
     ``OperatorSet.plan_pair``); ``diag``: the matrix diagonal in original
     row order.
     """
-    from repro.kernels import plan as _kp
-
     diag = jnp.asarray(diag)
     b = jnp.asarray(b)
     if (plan.ephemeral or plan.inv_cat is None
@@ -232,8 +230,25 @@ def jacobi_pcg_stored(mat, plan, diag: jnp.ndarray, b: jnp.ndarray, *,
         _obs.record_solve("jacobi_pcg_stored", info, path="fallback")
         return plan.from_stored(x_s), info
 
+    fn = stored_solve_fn(plan, b, tol=tol, maxiter=maxiter, dtype=dtype)
+    x0_s = jnp.zeros((plan.total_stored,),
+                     dtype if dtype is not None else b.dtype)
+    x, info = fn(mat, plan._device_operands(), diag, b, x0_s)
+    _obs.record_solve("jacobi_pcg_stored", info, path="fused")
+    return x, info
+
+
+def stored_solve_fn(plan, b, *, tol: float, maxiter: int, dtype=None):
+    """The whole :func:`jacobi_pcg_stored` solve for a right-hand side
+    shaped like ``b`` (an array or ``jax.ShapeDtypeStruct``) as one jitted
+    function ``(mat, dev, diag, b, x0_s) -> (x, SolveInfo)``, cached on the
+    plan. ``dev`` is ``plan._device_operands()``; ``x0_s``, the
+    stored-order initial guess, is donated."""
+    from repro.kernels import plan as _kp
+
     sdtype = jnp.dtype(dtype if dtype is not None else b.dtype)
-    key = ("jpcg_stored", float(tol), int(maxiter), b.shape, sdtype.name)
+    key = ("jpcg_stored", float(tol), int(maxiter), tuple(b.shape),
+           sdtype.name)
     fn = plan._fns.get(key)
     if fn is None:
         def solve(mat_a, dev, diag_a, b_a, x0_s):
@@ -254,10 +269,7 @@ def jacobi_pcg_stored(mat, plan, diag: jnp.ndarray, b: jnp.ndarray, *,
 
         fn = jax.jit(solve, donate_argnums=_donate(4))
         plan._fns[key] = fn
-    x0_s = jnp.zeros((plan.total_stored,), sdtype)
-    x, info = fn(mat, plan._device_operands(), diag, b, x0_s)
-    _obs.record_solve("jacobi_pcg_stored", info, path="fused")
-    return x, info
+    return fn
 
 
 def jacobi_pcg_dist(dplan, diag: jnp.ndarray, b: jnp.ndarray, *,
@@ -282,7 +294,7 @@ def jacobi_pcg_dist(dplan, diag: jnp.ndarray, b: jnp.ndarray, *,
     """
     from jax.sharding import PartitionSpec as Pspec
 
-    from repro.parallel.sharding import shard_map_compat
+    from repro.parallel.sharding import shard_map_unchecked
 
     b = jnp.asarray(b)
     dtype = dtype or b.dtype
@@ -305,7 +317,7 @@ def jacobi_pcg_dist(dplan, diag: jnp.ndarray, b: jnp.ndarray, *,
                             maxiter=maxiter, dtype=dtype, dot=dot, norm=norm)
             return x_l[None], info.iters, info.relres, info.history
 
-        f = shard_map_compat(
+        f = shard_map_unchecked(
             body, dplan.mesh,
             in_specs=(dplan.dev_specs, Pspec(ax), Pspec(ax)),
             out_specs=(Pspec(ax), Pspec(), Pspec(), Pspec()))
@@ -501,7 +513,7 @@ def adaptive_pcg_dist(ladder, diag: jnp.ndarray, b: jnp.ndarray, *,
     from jax.sharding import PartitionSpec as Pspec
 
     from repro.distributed import halo as dh
-    from repro.parallel.sharding import shard_map_compat
+    from repro.parallel.sharding import shard_map_unchecked
 
     b = jnp.asarray(b)
     dtype = dtype or b.dtype
@@ -539,7 +551,7 @@ def adaptive_pcg_dist(ladder, diag: jnp.ndarray, b: jnp.ndarray, *,
                 dot=dot, norm=norm, prestage=pre)
             return (x_l[None],) + tuple(info)
 
-        f = shard_map_compat(
+        f = shard_map_unchecked(
             body, ladder.mesh,
             in_specs=(ladder.dev_specs, Pspec(ax), Pspec(ax)),
             out_specs=(Pspec(ax),) + (Pspec(),) * 7)

@@ -22,7 +22,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.data import DataConfig, SyntheticTokenStream
@@ -31,7 +31,7 @@ from repro.models.config import ModelConfig
 from repro.optim import (OptConfig, TrainState, apply_updates, init_state,
                          zero_spec_tree)
 from repro.optim.compression import compress
-from repro.parallel import shard_map_compat, tree_shardings_shaped
+from repro.parallel import shard_map_unchecked, tree_shardings_shaped
 from repro.train.checkpoint import CheckpointManager
 from repro.train.fault import PreemptionGuard, StepMonitor
 
@@ -71,7 +71,8 @@ class Trainer:
         self.tcfg = tcfg
         self.log = log_fn
         self.mesh = mesh or jax.make_mesh(
-            (tcfg.data_axis, tcfg.model_axis), ("data", "model"))
+            (tcfg.data_axis, tcfg.model_axis), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
         self.ckpt = CheckpointManager(tcfg.ckpt_dir, keep=tcfg.keep)
         self.monitor = StepMonitor(threshold=tcfg.straggler_threshold)
         self.data = SyntheticTokenStream(DataConfig(
@@ -194,7 +195,7 @@ class Trainer:
 
         def train_step(state, err, batch):
             shapes = jax.tree.map(lambda x: x, state)
-            fn = shard_map_compat(
+            fn = shard_map_unchecked(
                 shard_step, mesh,
                 in_specs=(spec_like(state, rep), spec_like(err, rep),
                           spec_like(batch, bspec)),

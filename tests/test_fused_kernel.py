@@ -230,13 +230,16 @@ def test_policy_auto_interpret_stays_jnp():
 
 
 def test_policy_auto_compiled_prefers_fused():
-    """interpret=False models a compiled backend: auto must pick the
-    fused kernel when the stream is feasible and x fits residency."""
+    """interpret=False models a compiled TPU backend. Mosaic refuses the
+    fused kernel there, so auto must pick the jnp fused-stream body (the
+    same stream, decoded by XLA) and say why in plan.policy."""
     mat = packsell.from_csr(_int_csr(30, 40, 5, seed=2), C=8, sigma=32,
                             D=15, codec="fp16")
     plan = kplan.build_plan(mat, force="auto", interpret=False)
-    assert plan.variant == "fused"
-    assert "fused stream feasible" in plan.policy
+    assert plan.variant == "jnp" and not plan.interpret
+    assert plan.fused is not None and plan.cache_mode == "checkpoint"
+    assert "compiled TPU backend" in plan.policy
+    assert "dynamic_slice" in plan.policy
 
 
 def test_fused_forces_checkpoint_mode_and_logs():

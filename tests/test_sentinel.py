@@ -398,6 +398,22 @@ def test_profile_dispatch_fallback_marker(obs_on, monkeypatch):
     assert prof.wall_s > 0
 
 
+def test_profile_dispatch_raises_on_tpu(obs_on, monkeypatch):
+    """On a TPU a failed trace is an error: a wall clock is not a device
+    time."""
+    import jax
+    from repro.observe import profile
+
+    def boom(*a, **k):
+        raise RuntimeError("no profiler here")
+
+    monkeypatch.setattr(jax.profiler, "trace", boom)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    f = jax.jit(lambda x: x * 2.0)
+    with pytest.raises(RuntimeError, match="TPU trace failed"):
+        profile.profile_dispatch(f, np.float32(3.0), repeats=3)
+
+
 # ---------------------------------------------------------------------------
 # wiring: save_bench_json + serving endpoint
 # ---------------------------------------------------------------------------

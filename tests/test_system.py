@@ -315,3 +315,43 @@ class TestServing:
         eng.run()
         assert req.out_tokens[-1] == first
         assert len(req.out_tokens) == 1
+
+
+# ---------------------------------------------------------------------------
+# launch: compile cache, device peaks
+# ---------------------------------------------------------------------------
+
+
+class TestLaunch:
+    @pytest.fixture
+    def cache_config(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_compile_cache_defaults_to_checkout(self, cache_config,
+                                                monkeypatch):
+        from repro.launch import compile_cache as cc
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = cc.use_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+    def test_compile_cache_env_wins(self, cache_config, monkeypatch,
+                                    tmp_path):
+        from repro.launch import compile_cache as cc
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.use_compile_cache() == str(tmp_path)
+        # JAX reads the variable itself: nothing else is set in code
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_peak_bandwidth_by_device_kind(self):
+        from repro.launch import roofline as rl
+        peak = rl.peak_bandwidth("TPU v5 lite")
+        assert peak["bw_bytes_per_s"] == 819e9 and "v5e" in peak["source"]
+        with pytest.raises(KeyError, match="no published HBM peak"):
+            rl.peak_bandwidth("cpu")
