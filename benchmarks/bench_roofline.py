@@ -71,8 +71,8 @@ HLO_TOLERANCE = float(os.environ.get("REPRO_ROOFLINE_HLO_TOL", "8.0"))
 
 
 def _hlo_text(plan, mat, x) -> str:
-    """Compiled optimized-HLO text of one plan dispatch — feeds both the
-    byte cross-check and (``--profile``) the op->span attribution join."""
+    """Compiled optimized-HLO text of one plan dispatch (the byte
+    cross-check)."""
     fn = jax.jit(plan._execute, static_argnums=(3,))
     return fn.lower(plan._exec_mat(mat), plan._device_operands(), x,
                     False).compile().as_text()
@@ -84,25 +84,7 @@ def _hlo_bytes(txt: str) -> float:
     return float(hlo_cost.aggregate(txt)["bytes"])
 
 
-def _span_profile(plan, mat, x, hlo_txt: str) -> dict:
-    """Per-cell device-time span breakdown (``--profile``): run the plan
-    dispatch under ``observe.profile.profile_dispatch`` with the SAME
-    compiled-HLO text the byte cross-check lowered, so trace events join
-    against exactly the executable being measured."""
-    from repro.observe import profile as obs_profile
-
-    sp = obs_profile.profile_dispatch(
-        lambda v: plan.spmv(mat, v), x, hlo_texts=(hlo_txt,), repeats=10)
-    d = sp.to_dict()
-    # trim event payloads the scoreboard does not need
-    d["spans"] = {k: {kk: vv for kk, vv in v.items()}
-                  for k, v in d["spans"].items()
-                  if v["device_s"] > 0 or v["host_s"] > 0 or v["ops"]}
-    return d
-
-
-def _cells(name: str, a, peak: dict | None,
-           profile: bool = False) -> list[dict]:
+def _cells(name: str, a, peak: dict | None) -> list[dict]:
     """One scoreboard row per codec for matrix class ``name`` — both
     codecs timed interleaved so the fp16-vs-packed ratio is paired."""
     a = a.tocsr()
@@ -172,18 +154,10 @@ def _cells(name: str, a, peak: dict | None,
             pallas_vs_jnp=((t / float(np.median(ts_pl[key])))
                            if key in ts_pl else None),
         )
-        if profile:
-            prof = _span_profile(plan, mat, x, hlo_txt)
-            row["span_profile"] = prof
-            tag = ("profiler_unavailable" if prof["profiler_unavailable"]
-                   else f"accounted={prof['accounted_frac_of_wall']:.2f} "
-                        f"span_dev={prof['coverage_of_wall']:.2f} "
-                        f"host={prof['host_overhead_s'] * 1e6:.1f}us")
-            print(f"  profile {name}/{key}: {tag}")
         rows.append(row)
         common.emit("roofline_spmv", f"{name}_{key}",
                     **{k: v for k, v in row.items()
-                       if k not in ("klass", "span_profile")})
+                       if k != "klass"})
     return rows
 
 
@@ -216,11 +190,8 @@ def _legacy_dryrun_cells() -> list[dict]:
     return out
 
 
-def run(scale: str | None = None, profile: bool | None = None) -> None:
+def run(scale: str | None = None) -> None:
     scale = scale or common.SCALE
-    if profile is None:
-        profile = os.environ.get("REPRO_BENCH_PROFILE", "0") not in (
-            "0", "", "false")
     prev = observe.enable(True)          # the run records itself
     try:
         dev = jax.devices()[0]
@@ -234,13 +205,12 @@ def run(scale: str | None = None, profile: bool | None = None) -> None:
                         source=peak["source"])
         cells = []
         for name, a in testmats.suite("tiny").items():
-            cells.extend(_cells(name, a, peak, profile=profile))
+            cells.extend(_cells(name, a, peak))
 
         bad = [f"{c['klass']}/{c['codec']}{c['D']}" for c in cells
                if not c["hlo_within_tolerance"]]
         payload = dict(
             scale=scale, backend=jax.default_backend(),
-            profiled=bool(profile),
             peak_bandwidth=peak,
             hlo_tolerance=HLO_TOLERANCE,
             hlo_cells_out_of_tolerance=bad,
@@ -266,8 +236,5 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", default=None)
-    ap.add_argument("--profile", action="store_true",
-                    help="attach a per-cell device-time span breakdown "
-                         "(observe.profile) to every scoreboard cell")
     ns = ap.parse_args()
-    run(ns.scale, profile=ns.profile or None)
+    run(ns.scale)

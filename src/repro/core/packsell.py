@@ -31,6 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
 
+from repro.observe import metrics as _obs
+
 from . import codecs as cd
 from . import delta as de
 
@@ -160,8 +162,9 @@ def _bucket_spmv_scan(pack, d0, xc, codec, D, mlim, compute_dtype):
         pc = pack[:, j0:j0 + _SCAN_CHUNK, :]
         v, d = cd.unpack_words_jnp(pc, codec, D)
         cols = carry[:, None, :] + jnp.cumsum(d.astype(jnp.int32), axis=1)
-        xv = jnp.take(xc, jnp.minimum(cols, mlim).reshape(-1),
-                      axis=0, mode="clip").reshape(cols.shape)
+        with _obs.span("packsell.x_gather"):
+            xv = jnp.take(xc, jnp.minimum(cols, mlim).reshape(-1),
+                          axis=0, mode="clip").reshape(cols.shape)
         t = t + jnp.sum(v.astype(compute_dtype) * xv, axis=1)
         carry = cols[:, -1, :]
     return t
@@ -179,7 +182,8 @@ def _bucket_spmv_loop(pack, d0, xc, codec, D, mlim, compute_dtype):
         c, t = carry
         v, d = cd.unpack_words_jnp(pack[:, j, :], codec, D)
         c = c + d.astype(jnp.int32)
-        xv = jnp.take(xc, jnp.minimum(c, mlim), axis=0, mode="clip")
+        with _obs.span("packsell.x_gather"):
+            xv = jnp.take(xc, jnp.minimum(c, mlim), axis=0, mode="clip")
         t = t + v.astype(compute_dtype) * xv
         return c, t
 
@@ -197,8 +201,9 @@ def _bucket_spmm_scan(pack, d0, xc, codec, D, mlim, compute_dtype):
         pc = pack[:, j0:j0 + _SCAN_CHUNK, :]
         v, d = cd.unpack_words_jnp(pc, codec, D)
         cols = carry[:, None, :] + jnp.cumsum(d.astype(jnp.int32), axis=1)
-        xv = jnp.take(xc, jnp.minimum(cols, mlim).reshape(-1),
-                      axis=0, mode="clip").reshape(cols.shape + (nb,))
+        with _obs.span("packsell.x_gather"):
+            xv = jnp.take(xc, jnp.minimum(cols, mlim).reshape(-1),
+                          axis=0, mode="clip").reshape(cols.shape + (nb,))
         t = t + jnp.sum(v.astype(compute_dtype)[..., None] * xv, axis=1)
         carry = cols[:, -1, :]
     return t
@@ -214,8 +219,9 @@ def _bucket_spmm_loop(pack, d0, xc, codec, D, mlim, compute_dtype):
         c, t = carry
         v, d = cd.unpack_words_jnp(pack[:, j, :], codec, D)
         c = c + d.astype(jnp.int32)
-        xv = jnp.take(xc, jnp.minimum(c, mlim).reshape(-1),
-                      axis=0, mode="clip").reshape(S, C, nb)
+        with _obs.span("packsell.x_gather"):
+            xv = jnp.take(xc, jnp.minimum(c, mlim).reshape(-1),
+                          axis=0, mode="clip").reshape(S, C, nb)
         t = t + v.astype(compute_dtype)[..., None] * xv
         return c, t
 
@@ -320,7 +326,14 @@ def _bucket_slices(widths: np.ndarray, strategy: str):
 def from_csr(a: sp.csr_matrix, *, C: int = 128, sigma: int = 256, D: int = 15,
              codec: str = "fp16", bucket_strategy: str = "pow2",
              device: bool = True) -> PackSELLMatrix:
-    """Build a PackSELL matrix from a scipy CSR matrix."""
+    """Build a PackSELL matrix from a scipy CSR matrix (host span
+    ``packsell.pack``, with a child span per stage)."""
+    with _obs.host_span("packsell.pack"):
+        return _from_csr(a, C=C, sigma=sigma, D=D, codec=codec,
+                         bucket_strategy=bucket_strategy, device=device)
+
+
+def _from_csr(a, *, C, sigma, D, codec, bucket_strategy, device):
     if sigma % C != 0:
         raise ValueError(f"sigma ({sigma}) must be a multiple of C ({C})")
     a = a.tocsr()
@@ -343,67 +356,81 @@ def from_csr(a: sp.csr_matrix, *, C: int = 128, sigma: int = 256, D: int = 15,
         raise ValueError(f"D={D} outside [{codec_obj.min_D},{codec_obj.max_D}] "
                          f"for codec {codec}")
 
-    k_left = de.lower_bandwidth(indptr, indices, n)
-    d0_row = de.d0_for_rows(n, sigma, k_left)
-    deltas, n_dummies, stored_len = de.encode_rows(indptr, indices, d0_row, D)
-    w_values, w_deltas, w_flags, _, n_words = de.emit_word_stream(
-        values, deltas, n_dummies)
-    words = cd.pack_words_np(w_values, w_deltas, w_flags, codec_obj, D)
+    with _obs.host_span("packsell.pack.encode"):
+        k_left = de.lower_bandwidth(indptr, indices, n)
+        d0_row = de.d0_for_rows(n, sigma, k_left)
+        deltas, n_dummies, stored_len = de.encode_rows(indptr, indices,
+                                                       d0_row, D)
+    with _obs.host_span("packsell.pack.words"):
+        w_values, w_deltas, w_flags, _, n_words = de.emit_word_stream(
+            values, deltas, n_dummies)
+        words = cd.pack_words_np(w_values, w_deltas, w_flags, codec_obj, D)
     row_word_start = _cumsum0(stored_len)
 
-    outrow, perm = _sigma_sort(stored_len, n, sigma, C)
-    n_padded = len(outrow)
-    S = n_padded // C
+    with _obs.host_span("packsell.pack.slices"):
+        outrow, perm = _sigma_sort(stored_len, n, sigma, C)
+        n_padded = len(outrow)
+        S = n_padded // C
 
-    stored_len_padded = np.zeros(n_padded, dtype=np.int64)
-    valid = outrow < n
-    stored_len_padded[valid] = stored_len[outrow[valid]]
-    slice_width = stored_len_padded.reshape(S, C).max(axis=1)
-    words_sell_padded = int((slice_width * C).sum())
+        stored_len_padded = np.zeros(n_padded, dtype=np.int64)
+        valid = outrow < n
+        stored_len_padded[valid] = stored_len[outrow[valid]]
+        slice_width = stored_len_padded.reshape(S, C).max(axis=1)
+        words_sell_padded = int((slice_width * C).sum())
 
-    d0_slice = np.maximum((np.arange(S) * C // sigma) * sigma - k_left, 0)
+        d0_slice = np.maximum((np.arange(S) * C // sigma) * sigma - k_left,
+                              0)
 
-    # per-row last column (band-kernel window metadata); empty rows -> d0
-    lastcol_row = d0_row.copy()
-    nz_rows = np.diff(indptr) > 0
-    lastcol_row[nz_rows] = indices[indptr[1:][nz_rows] - 1]
-    lastcol_padded = np.zeros(n_padded, dtype=np.int64)
-    lastcol_padded[valid] = lastcol_row[outrow[valid]]
-    maxcol_slice = lastcol_padded.reshape(S, C).max(axis=1)
+        # per-row last column (band-kernel window metadata); empty rows ->
+        # d0
+        lastcol_row = d0_row.copy()
+        nz_rows = np.diff(indptr) > 0
+        lastcol_row[nz_rows] = indices[indptr[1:][nz_rows] - 1]
+        lastcol_padded = np.zeros(n_padded, dtype=np.int64)
+        lastcol_padded[valid] = lastcol_row[outrow[valid]]
+        maxcol_slice = lastcol_padded.reshape(S, C).max(axis=1)
 
-    buckets = _bucket_slices(slice_width, bucket_strategy)
-    packs, d0s, outrows, maxcols_l = [], [], [], []
-    words_bucketed = 0
-    # guard row for the gather below (padding rows index word 0 harmlessly)
-    words_g = words if n_words > 0 else np.zeros(1, dtype=np.uint32)
-    for slice_ids, w_b in buckets:
-        rows = (slice_ids[:, None] * C + np.arange(C)[None, :]).reshape(-1)
-        orig = outrow[rows]                         # [S_b*C]
-        lens = stored_len_padded[rows]              # [S_b*C]
-        starts = np.where(orig < n, row_word_start[np.minimum(orig, n - 1)], 0)
-        j = np.arange(w_b, dtype=np.int64)
-        idx = starts[:, None] + j[None, :]          # [S_b*C, w_b]
-        ok = j[None, :] < lens[:, None]
-        gathered = np.where(ok, words_g[np.minimum(idx, len(words_g) - 1)],
-                            PAD_WORD)
-        pack3d = gathered.reshape(len(slice_ids), C, w_b).transpose(0, 2, 1)
-        packs.append(np.ascontiguousarray(pack3d.astype(np.uint32)))
-        d0s.append(d0_slice[slice_ids].astype(np.int32))
-        outrows.append(np.where(orig < n, orig, n).astype(np.int32))
-        maxcols_l.append(maxcol_slice[slice_ids].astype(np.int32))
-        words_bucketed += pack3d.size
+        buckets = _bucket_slices(slice_width, bucket_strategy)
+        packs, d0s, outrows, maxcols_l = [], [], [], []
+        words_bucketed = 0
+        # guard row for the gather below (padding rows index word 0
+        # harmlessly)
+        words_g = words if n_words > 0 else np.zeros(1, dtype=np.uint32)
+        for slice_ids, w_b in buckets:
+            rows = (slice_ids[:, None] * C
+                    + np.arange(C)[None, :]).reshape(-1)
+            orig = outrow[rows]                         # [S_b*C]
+            lens = stored_len_padded[rows]              # [S_b*C]
+            starts = np.where(orig < n,
+                              row_word_start[np.minimum(orig, n - 1)], 0)
+            j = np.arange(w_b, dtype=np.int64)
+            idx = starts[:, None] + j[None, :]          # [S_b*C, w_b]
+            ok = j[None, :] < lens[:, None]
+            gathered = np.where(
+                ok, words_g[np.minimum(idx, len(words_g) - 1)], PAD_WORD)
+            pack3d = gathered.reshape(len(slice_ids), C,
+                                      w_b).transpose(0, 2, 1)
+            packs.append(np.ascontiguousarray(pack3d.astype(np.uint32)))
+            d0s.append(d0_slice[slice_ids].astype(np.int32))
+            outrows.append(np.where(orig < n, orig, n).astype(np.int32))
+            maxcols_l.append(maxcol_slice[slice_ids].astype(np.int32))
+            words_bucketed += pack3d.size
 
-    to_dev = jnp.asarray if device else (lambda v: v)
-    return PackSELLMatrix(
-        packs=tuple(to_dev(p) for p in packs),
-        d0s=tuple(to_dev(d) for d in d0s),
-        outrows=tuple(to_dev(o) for o in outrows),
-        maxcols=tuple(to_dev(mc) for mc in maxcols_l),
-        perm=to_dev(perm),
-        n=n, m=m, C=C, sigma=sigma, D=D, codec_name=codec, k_left=k_left,
-        nnz=int(a.nnz), n_dummy=int(n_dummies.sum()),
-        words_sell_padded=words_sell_padded, words_bucketed=int(words_bucketed),
-    )
+    with _obs.host_span("packsell.pack.to_device"):
+        to_dev = jnp.asarray if device else (lambda v: v)
+        mat = PackSELLMatrix(
+            packs=tuple(to_dev(p) for p in packs),
+            d0s=tuple(to_dev(d) for d in d0s),
+            outrows=tuple(to_dev(o) for o in outrows),
+            maxcols=tuple(to_dev(mc) for mc in maxcols_l),
+            perm=to_dev(perm),
+            n=n, m=m, C=C, sigma=sigma, D=D, codec_name=codec,
+            k_left=k_left,
+            nnz=int(a.nnz), n_dummy=int(n_dummies.sum()),
+            words_sell_padded=words_sell_padded,
+            words_bucketed=int(words_bucketed),
+        )
+    return mat
 
 
 def from_dense(a: np.ndarray, **kw) -> PackSELLMatrix:

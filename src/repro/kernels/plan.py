@@ -64,7 +64,6 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import os
-import time
 import weakref
 from typing import Optional
 
@@ -254,8 +253,9 @@ def _cursor_spmv(pack, cols, xc, codec, D):
     one reduction — no runtime cumsum, but one int32 streamed per word."""
     S, w, C = pack.shape
     v, _ = cd.unpack_words_jnp(pack, codec, D)
-    xv = jnp.take(xc, cols.reshape(-1), axis=0,
-                  mode="clip").reshape(S, w, C)
+    with _obs.span("packsell.x_gather"):
+        xv = jnp.take(xc, cols.reshape(-1), axis=0,
+                      mode="clip").reshape(S, w, C)
     return jnp.sum(v.astype(jnp.float32) * xv, axis=1)
 
 
@@ -270,8 +270,9 @@ def _cursor_spmm(pack, cols, xc, codec, D):
     for j0 in range(0, w, chunk):
         vc = v[:, j0:j0 + chunk, :].astype(jnp.float32)
         cc = cols[:, j0:j0 + chunk, :]
-        xv = jnp.take(xc, cc.reshape(-1), axis=0,
-                      mode="clip").reshape(cc.shape + (nb,))
+        with _obs.span("packsell.x_gather"):
+            xv = jnp.take(xc, cc.reshape(-1), axis=0,
+                          mode="clip").reshape(cc.shape + (nb,))
         acc = acc + jnp.sum(vc[..., None] * xv, axis=1)
     return acc
 
@@ -279,13 +280,12 @@ def _cursor_spmm(pack, cols, xc, codec, D):
 def _build_cursor_cache(mat: PackSELLMatrix):
     """Decode every bucket's column cursors once (host-side numpy): the
     prefix-sum of word deltas, clamped to [0, m-1] exactly as the runtime
-    decode would."""
+    decode would. Host arrays: the plan build puts them on the device."""
     mlim = max(mat.m - 1, 0)
     cols = []
     for pack, d0 in zip(mat.packs, mat.d0s):
         cum0 = _bucket_cursor_prefix(pack, d0, mat.codec, mat.D)
-        cols.append(jnp.asarray(
-            np.minimum(cum0[:, 1:, :], mlim).astype(np.int32)))
+        cols.append(np.minimum(cum0[:, 1:, :], mlim).astype(np.int32))
     return tuple(cols)
 
 
@@ -439,10 +439,11 @@ def _build_fused_stream(mat: PackSELLMatrix, *, trim: bool = True,
                         wr: int | None = None):
     """Repack the bucketed words into the fused ragged-group layout, once,
     host-side (DESIGN.md §10.1). Returns ``((words3d, ckpt), layout,
-    orders)`` — ``orders`` is the per-bucket slice permutation the caller
-    must bake into ``outrow_cat`` — or ``(None, None, None)`` when no
-    encoding fits (a group's column span overflows every offset field —
-    the caller falls back to the full cursor cache).
+    orders)``, the stream as host arrays — ``orders`` is the per-bucket
+    slice permutation the caller must bake into ``outrow_cat`` — or
+    ``(None, None, None)`` when no encoding fits (a group's column span
+    overflows every offset field — the caller falls back to the full
+    cursor cache).
 
     Each bucket's slices are sorted by content width (descending run
     count, stable), their word runs padded to a multiple of ``wr`` with
@@ -555,8 +556,7 @@ def _build_fused_stream(mat: PackSELLMatrix, *, trim: bool = True,
     layout = FusedLayout(
         wr=wr, groups=g0, C=C, words_exact=total,
         segments=tuple(segs), encoding=encoding, scale=scale)
-    return ((jnp.asarray(words3d), jnp.asarray(ckpt.astype(np.int32))),
-            layout, orders)
+    return (words3d, ckpt.astype(np.int32)), layout, orders
 
 
 def _fused_decode(w, codec, D, layout: FusedLayout):
@@ -621,8 +621,9 @@ def _fused_part_spmv(words3d, ckpt, xc, codec, D, layout: FusedLayout):
     G, wr, C = words3d.shape
     v, local = _fused_decode(words3d, codec, D, layout)
     cols = ckpt[:, None, :] + local
-    xv = jnp.take(xc, cols.reshape(-1), axis=0,
-                  mode="clip").reshape(G, wr, C)
+    with _obs.span("packsell.x_gather"):
+        xv = jnp.take(xc, cols.reshape(-1), axis=0,
+                      mode="clip").reshape(G, wr, C)
     p = v * xv
     acc = p[:, 0, :]
     for j in range(1, wr):
@@ -641,8 +642,9 @@ def _fused_part_spmm(words3d, ckpt, xc, codec, D, layout: FusedLayout):
     for j in range(wr):
         v, local = _fused_decode(words3d[:, j, :], codec, D, layout)
         cols = ckpt + local
-        xv = jnp.take(xc, cols.reshape(-1), axis=0,
-                      mode="clip").reshape(G, C, nb)
+        with _obs.span("packsell.x_gather"):
+            xv = jnp.take(xc, cols.reshape(-1), axis=0,
+                          mode="clip").reshape(G, C, nb)
         t = v[..., None] * xv
         acc = t if acc is None else acc + t
     if acc is None:
@@ -655,7 +657,7 @@ def _build_block_checkpoints(mat: PackSELLMatrix, tiles):
     Pallas kernels: the cursor before word ``wi * wb`` of each stored row.
     Replaces the kernels' d0-seeded sequential VMEM cursor carry
     (``packsell_spmv.py``); recomputed on :meth:`SpMVPlan.retile` because
-    the granularity is the width-block size ``wb``."""
+    the granularity is the width-block size ``wb``. Host arrays."""
     out = []
     for (sb, wb), pack, d0 in zip(tiles, mat.packs, mat.d0s):
         words = np.asarray(pack)
@@ -663,7 +665,7 @@ def _build_block_checkpoints(mat: PackSELLMatrix, tiles):
         nw = -(-w // wb)
         cum0 = _bucket_cursor_prefix(pack, d0, mat.codec, mat.D)
         ck = cum0[:, ::wb, :][:, :nw, :]
-        out.append(jnp.asarray(ck.astype(np.int32)))
+        out.append(ck.astype(np.int32))
     return tuple(out)
 
 
@@ -685,14 +687,14 @@ def stored_unpermute(t, inv_cat):
     return jnp.take(t, inv_cat, axis=0, mode="clip", unique_indices=True)
 
 
-def _build_inverse_perm(mat: PackSELLMatrix, outrow_cat: jnp.ndarray):
+def _build_inverse_perm(mat: PackSELLMatrix, outrow_cat):
     """inv[r] = stored slot of original row r (each row has exactly one),
-    turning the σ-scatter epilogue into a gather."""
+    turning the σ-scatter epilogue into a gather. A host array."""
     outrow_np = np.asarray(outrow_cat)
     valid = outrow_np < mat.n
     inv = np.zeros(mat.n, np.int32)
     inv[outrow_np[valid]] = np.nonzero(valid)[0].astype(np.int32)
-    return jnp.asarray(inv)
+    return inv
 
 
 # ---------------------------------------------------------------------------
@@ -946,15 +948,36 @@ class SpMVPlan:
                 words_bucketed=mat.words_bucketed)
         return self._view
 
-    def _obs_record(self, mat: PackSELLMatrix, kind: str) -> None:
-        """Per-dispatch flight-recorder record (DESIGN.md §12): variant,
-        checkpoint width ``wr``, hot-path stream bytes and bytes/nnz.
-        Called only from host entry points with concrete operands — never
-        from inside a trace, where it would freeze at trace time. The
-        derived byte figures are per-plan constants, computed once and
-        parked in ``_fns`` (cleared by :meth:`retile`, so they re-derive)."""
-        bump = self._fns.get(("_obs", kind))
-        if bump is None:
+    def _gauge_counts(self, mat: PackSELLMatrix) -> None:
+        """Per-plan gauges, set when the plan is built (and when a retile
+        rebuilds its stream), never per call: the 32-bit words the
+        executed decode streams per call (the fused stream on the
+        checkpoint path, the bucketed packs otherwise), the int32
+        cursor-cache or checkpoint words it reads, dummies and nnz."""
+        dcs = self.decode_cache_stats()
+        on_stream = (self.fused is not None
+                     and self.variant in ("jnp", "fused"))
+        lab = dict(variant=self.variant, codec=mat.codec_name,
+                   cache_mode=self.cache_mode)
+        _obs.gauge("plan.decode_words",
+                   dcs["fused_stream_bytes"] // 4 if on_stream
+                   else int(mat.words_bucketed), **lab)
+        _obs.gauge("plan.cache_words", dcs["decode_cache_bytes"] // 4,
+                   **lab)
+        _obs.gauge("plan.dummies", int(mat.n_dummy), **lab)
+        _obs.gauge("plan.nnz", int(mat.nnz), **lab)
+
+    def _obs_handles(self, mat: PackSELLMatrix, kind: str):
+        """``(span, bump)`` for one recorded dispatch (DESIGN.md §12): the
+        ``packsell.dispatch{kind}`` host span factory and the counter bump
+        of variant, checkpoint width ``wr``, hot-path stream bytes and
+        bytes/nnz. Used only from host entry points with concrete
+        operands — never from inside a trace, where a record would freeze
+        at trace time. The derived byte figures are per-plan constants,
+        computed once and parked in ``_fns`` (cleared by :meth:`retile`,
+        so they re-derive)."""
+        handles = self._fns.get(("_obs", kind))
+        if handles is None:
             dcs = self.decode_cache_stats()
             stream = (dcs["fused_stream_bytes"] or 4 * self.total_words) \
                 + dcs["decode_cache_bytes"]
@@ -973,8 +996,10 @@ class SpMVPlan:
             bump = _obs.counter_bump((
                 (_obs.series_key("spmv.dispatch", kind=kind, **lab), 1),
                 (_obs.series_key("spmv.nnz", **lab), int(mat.nnz))))
-            self._fns[("_obs", kind)] = bump
-        bump()
+            handles = (_obs.host_span_handle("packsell.dispatch", kind=kind),
+                       bump)
+            self._fns[("_obs", kind)] = handles
+        return handles
 
     def spmv(self, mat: PackSELLMatrix, x: jnp.ndarray, *,
              permuted: bool = False) -> jnp.ndarray:
@@ -983,7 +1008,12 @@ class SpMVPlan:
         if self.ephemeral or _is_traced(mat):
             return self._execute(mat, self._device_operands(), x, permuted)
         if _obs.enabled() and not isinstance(x, jax.core.Tracer):
-            self._obs_record(mat, "spmv")
+            span, bump = self._obs_handles(mat, "spmv")
+            with span():
+                bump()
+                return self._dispatch("spmv")(self._exec_mat(mat),
+                                              self._device_operands(), x,
+                                              permuted)
         return self._dispatch("spmv")(self._exec_mat(mat),
                                       self._device_operands(), x,
                                       permuted)
@@ -1009,7 +1039,12 @@ class SpMVPlan:
             return self._execute_mm(mat, self._device_operands(), x,
                                     permuted)
         if _obs.enabled() and not isinstance(x, jax.core.Tracer):
-            self._obs_record(mat, "spmm")
+            span, bump = self._obs_handles(mat, "spmm")
+            with span():
+                bump()
+                return self._dispatch("spmm")(self._exec_mat(mat),
+                                              self._device_operands(), x,
+                                              permuted)
         return self._dispatch("spmm")(self._exec_mat(mat),
                                       self._device_operands(), x,
                                       permuted)
@@ -1115,7 +1150,8 @@ class SpMVPlan:
         if self.kckpts is not None:
             if mat is None:
                 raise ValueError("cannot retile checkpoints: matrix is gone")
-            self.kckpts = _build_block_checkpoints(mat, tiles)
+            self.kckpts = jax.device_put(
+                _build_block_checkpoints(mat, tiles))
         if (new_wr is not None and self.fused is not None
                 and self.fused_layout is not None
                 and new_wr != self.fused_layout.wr):
@@ -1128,20 +1164,16 @@ class SpMVPlan:
                 raise ValueError(
                     f"wr={new_wr}: fused stream infeasible (group column "
                     "span overflows every compact offset encoding)")
-            self.fused, self.fused_layout = fused, layout
+            self.fused_layout = layout
             # the slice sort depends on runs-per-slice = f(wr): re-bake the
             # stored order and both inverse-permutation forms
-            outs = [np.asarray(o).reshape(len(ordr), -1)[ordr].reshape(-1)
-                    for o, ordr in zip(mat.outrows, orders)]
-            self.outrow_cat = (jnp.asarray(np.concatenate(outs)) if outs
-                               else jnp.zeros((0,), jnp.int32))
-            self.inv_cat = _build_inverse_perm(mat, self.outrow_cat)
-            inv = np.asarray(self.inv_cat)
-            self.inv2_cat = jnp.asarray(np.stack(
-                [inv // mat.C, inv % mat.C], axis=1).astype(np.int32))
+            outrow, inv, inv2 = _stored_order(mat, orders, True)
+            (self.fused, self.outrow_cat, self.inv_cat,
+             self.inv2_cat) = jax.device_put((fused, outrow, inv, inv2))
             self.tiles = tiles
             self._fns.clear()
             _quick_validate(mat, self)
+            self._gauge_counts(mat)
             return
         self.tiles = tiles
         self._fns.clear()
@@ -1170,17 +1202,32 @@ def build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
     checkpoint width instead of the modeled pick (the autotune sweep's
     third axis).
     """
-    t0 = time.perf_counter()
-    with _obs.span("packsell.plan_build"):
+    with _obs.host_span("packsell.plan_build"):
         plan = _build_plan(mat, sb=sb, wb=wb, hw=hw, force=force,
                            interpret=interpret, decode_cache=decode_cache,
                            fused_trim=fused_trim, ckpt_wr=ckpt_wr)
     if not plan.ephemeral:
         _obs.inc("plan.build", variant=plan.variant,
                  cache_mode=plan.cache_mode)
-        _obs.observe("plan.build_s", time.perf_counter() - t0,
-                     variant=plan.variant)
+        plan._gauge_counts(mat)
     return plan
+
+
+def _stored_order(mat: PackSELLMatrix, orders, two_d: bool):
+    """Host arrays of the plan's stored order: ``outrow_cat`` (the fused
+    layout's per-bucket slice sort ``orders`` baked in, where given), the
+    inverse permutation and, where ``two_d``, its (slice, lane) form."""
+    if orders is not None:
+        outs = [np.asarray(o).reshape(len(ordr), -1)[ordr].reshape(-1)
+                for o, ordr in zip(mat.outrows, orders)]
+    else:
+        outs = [np.asarray(o).reshape(-1) for o in mat.outrows]
+    outrow = (np.concatenate(outs) if outs
+              else np.zeros((0,), np.int32))
+    inv = _build_inverse_perm(mat, outrow)
+    inv2 = (np.stack([inv // mat.C, inv % mat.C], axis=1).astype(np.int32)
+            if two_d else None)
+    return outrow, inv, inv2
 
 
 def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
@@ -1243,8 +1290,9 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
             raise ValueError(
                 f"x too large for VMEM residency (m={mat.m}); the fused "
                 "kernel gathers the whole x — use band/jnp")
-        fused, layout, orders = _build_fused_stream(mat, trim=fused_trim,
-                                                    wr=ckpt_wr)
+        with _obs.host_span("packsell.plan_build.stream"):
+            fused, layout, orders = _build_fused_stream(
+                mat, trim=fused_trim, wr=ckpt_wr)
         if fused is None:
             # forced fused but no compact encoding fits: demote to the
             # jnp variant on the full cursor cache, loudly
@@ -1280,9 +1328,9 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
             mode = "checkpoint"
     elif variant == "jnp":
         if mode == "checkpoint" and fused is None:
-            fused, layout, orders = _build_fused_stream(mat,
-                                                        trim=fused_trim,
-                                                        wr=ckpt_wr)
+            with _obs.host_span("packsell.plan_build.stream"):
+                fused, layout, orders = _build_fused_stream(
+                    mat, trim=fused_trim, wr=ckpt_wr)
             if fused is None:
                 # a group's column span overflows every compact offset
                 # encoding — fall back to the full cursor cache, loudly
@@ -1291,19 +1339,19 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
                            "span overflow), fell back to full cursor "
                            "cache")
         if mode == "full":
-            cols = _build_cursor_cache(mat)
+            with _obs.host_span("packsell.plan_build.cache"):
+                cols = _build_cursor_cache(mat)
     elif mode == "checkpoint":
-        kckpts = _build_block_checkpoints(mat, tiles)
-    if orders is not None:
-        # bake the fused layout's per-bucket slice sort into the plan's
+        with _obs.host_span("packsell.plan_build.cache"):
+            kckpts = _build_block_checkpoints(mat, tiles)
+    with _obs.host_span("packsell.plan_build.inverse"):
+        # the fused layout's per-bucket slice sort is baked into the
         # stored order (outputs of the fused tail land in sorted order)
-        outs = [np.asarray(o).reshape(len(ordr), -1)[ordr].reshape(-1)
-                for o, ordr in zip(mat.outrows, orders)]
-        outrow_cat = (jnp.asarray(np.concatenate(outs)) if outs
-                      else jnp.zeros((0,), jnp.int32))
-    else:
-        outrow_cat = (jnp.concatenate([o.reshape(-1) for o in mat.outrows])
-                      if n_buckets else jnp.zeros((0,), jnp.int32))
+        outrow_cat, inv, inv2 = _stored_order(mat, orders,
+                                              fused is not None)
+    with _obs.host_span("packsell.plan_build.to_device"):
+        fused, cols, kckpts, outrow_cat, inv, inv2 = jax.device_put(
+            (fused, cols, kckpts, outrow_cat, inv, inv2))
     plan = SpMVPlan(
         variant=variant, policy=f"{variant} ({reason})", hw=hw,
         interpret=interpret, tiles=tiles,
@@ -1311,10 +1359,7 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
         outrow_cat=outrow_cat, n=mat.n, m=mat.m,
         total_stored=sum(int(p.shape[0]) * int(p.shape[2])
                          for p in mat.packs),
-        inv_cat=(inv := _build_inverse_perm(mat, outrow_cat)),
-        inv2_cat=(None if fused is None else jnp.asarray(np.stack(
-            [np.asarray(inv) // mat.C, np.asarray(inv) % mat.C],
-            axis=1).astype(np.int32))),
+        inv_cat=inv, inv2_cat=inv2,
         cols=cols, cache_mode=mode, fused=fused, fused_layout=layout,
         kckpts=kckpts,
         total_words=sum(int(np.prod(p.shape)) for p in mat.packs),

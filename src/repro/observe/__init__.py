@@ -11,16 +11,18 @@ default (``REPRO_OBS=0``); flip it with the env var or :func:`enable`.
 """
 from __future__ import annotations
 
-from .metrics import (enable, enabled, export_json, gauge, inc, observe,
-                      raw_snapshot, record_trace, reset, snapshot, span)
+from .metrics import (enable, enabled, export_json, gauge, host_span,
+                      host_span_handle, inc, observe, raw_snapshot,
+                      record_trace, reset, snapshot, span)
 
 __all__ = [
-    "enable", "enabled", "export_json", "gauge", "inc", "observe",
+    "enable", "enabled", "export_json", "gauge", "host_span",
+    "host_span_handle", "inc", "observe",
     "raw_snapshot", "record_trace", "record_solve", "reset", "snapshot",
     "span", "report",
     # perf-sentinel layers (DESIGN.md §13): imported as submodules to keep
     # `import repro.observe` light — `from repro.observe import export,
-    # profile, trajectory`
+    # trajectory`
 ]
 
 

@@ -15,9 +15,15 @@ that name hot regions in XLA profiles. Two invariants shape the design:
   neither can change numerics, which is what the REPRO_OBS=1 bit-for-bit
   parity tests pin down.
 
+``host_span()`` times host work that no device op covers (packing, plan
+construction, dispatch): a ``TraceAnnotation`` on the profiler's host
+plane, on the device ops' clock, plus its host seconds in the histogram
+``span_s{span=<name>}``.
+
 Series naming follows ``subsystem.event`` with labels for dimensions, e.g.
-``spmv.dispatch{cache_mode=checkpoint,codec=fp16,variant=jnp}``. The full
-span/series naming map lives in DESIGN.md §12.
+``spmv.dispatch{cache_mode=checkpoint,codec=fp16,variant=jnp}``. Span and
+scope names come from :data:`SPAN_NAMES`; the full naming map lives in
+DESIGN.md §12.
 """
 from __future__ import annotations
 
@@ -26,12 +32,47 @@ import json
 import os
 import random
 import threading
+import time
 
 __all__ = [
     "enabled", "enable", "inc", "gauge", "observe", "record_trace",
     "series_key", "inc_many", "counter_bump", "snapshot", "raw_snapshot",
-    "reset", "export_json", "span",
+    "reset", "export_json", "span", "host_span", "host_span_handle",
+    "SPAN_NAMES",
 ]
+
+#: The program's span and scope names (DESIGN.md §12.2). Device scopes,
+#: planted by :func:`span` as ``named_scope`` metadata on the ops they
+#: enclose (``x_gather`` nests inside the decode scopes; the solver's
+#: vector work and its σ-permutes never nest inside an SpMV scope):
+_SCOPE_NAMES = (
+    "packsell.fused_decode",
+    "packsell.fused_kernel",
+    "packsell.bucket_decode",
+    "packsell.x_gather",
+    "packsell.gather_epilogue",
+    "packsell.halo_prestage",
+    "packsell.guard_checksum",
+    "packsell.solver_while",
+    "packsell.solver_vec",
+    "packsell.stored_permute",
+)
+#: host spans, timed by :func:`host_span` (a dotted suffix names a stage
+#: of its parent):
+_HOST_SPAN_NAMES = (
+    "packsell.pack",
+    "packsell.pack.encode",
+    "packsell.pack.words",
+    "packsell.pack.slices",
+    "packsell.pack.to_device",
+    "packsell.plan_build",
+    "packsell.plan_build.stream",
+    "packsell.plan_build.cache",
+    "packsell.plan_build.inverse",
+    "packsell.plan_build.to_device",
+    "packsell.dispatch",
+)
+SPAN_NAMES = _SCOPE_NAMES + _HOST_SPAN_NAMES
 
 
 def _env_on(val: str | None) -> bool:
@@ -146,27 +187,36 @@ def observe(name: str, value: float, **labels) -> None:
     if not _ENABLED:
         return
     k = _key(name, labels)
-    v = float(value)
     with _LOCK:
-        h = _HISTS.get(k)
-        if h is None:
-            _HISTS[k] = {"count": 1, "sum": v, "min": v, "max": v,
-                         "last": v, "res": [v]}
-        else:
-            h["count"] += 1
-            h["sum"] += v
-            h["min"] = min(h["min"], v)
-            h["max"] = max(h["max"], v)
-            h["last"] = v
-            res = h["res"]
-            if len(res) < _RES_CAP:
-                res.append(v)
-            else:
-                # uniform reservoir: each of the count values seen so far
-                # keeps an equal _RES_CAP/count chance of being resident
-                j = _RES_RNG.randrange(h["count"])
-                if j < _RES_CAP:
-                    res[j] = v
+        _hist_add(k, float(value))
+
+
+def _hist_add(k, v: float) -> None:
+    """Add ``v`` to histogram series ``k``. Callers hold ``_LOCK``, except
+    the host spans, which take the lock-free tradeoff of :func:`inc_many`
+    (a rare lost observation between threads on one series)."""
+    h = _HISTS.get(k)
+    if h is None:
+        _HISTS[k] = {"count": 1, "sum": v, "min": v, "max": v,
+                     "last": v, "res": [v]}
+        return
+    n = h["count"] = h["count"] + 1
+    h["sum"] += v
+    if v < h["min"]:
+        h["min"] = v
+    if v > h["max"]:
+        h["max"] = v
+    h["last"] = v
+    res = h["res"]
+    if n <= _RES_CAP:
+        res.append(v)
+    else:
+        # uniform reservoir: each of the n values seen so far keeps an
+        # equal _RES_CAP/n chance of being resident (random() is a third
+        # of randrange()'s cost on the per-call host-span path)
+        j = int(_RES_RNG.random() * n)
+        if j < _RES_CAP:
+            res[j] = v
 
 
 def _quantiles(res: list) -> dict:
@@ -266,3 +316,62 @@ def span(name: str):
 
     with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
         yield
+
+
+_NULL_SPAN = contextlib.nullcontext()
+
+
+class _HostSpan:
+    """One interval of a host span: a ``TraceAnnotation`` while a profiler
+    collects (the C++ ``TraceMe`` check is all it costs otherwise), and
+    its host seconds into histogram ``key``."""
+
+    __slots__ = ("_name", "_key", "_ann_cls", "_ann", "_t0")
+
+    def __init__(self, name: str, key, ann_cls):
+        self._name, self._key, self._ann_cls = name, key, ann_cls
+        self._ann = None
+
+    def __enter__(self):
+        if self._ann_cls.is_enabled():
+            self._ann = self._ann_cls(self._name)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _hist_add(self._key, dt)
+        return False
+
+
+def host_span(name: str, **labels):
+    """Time host work as span ``name``: a ``jax.profiler.TraceAnnotation``
+    on the profiler's host plane (same clock as the device ops) and the
+    host seconds into histogram ``span_s{span=name, **labels}``. A shared
+    null context when disabled: one predicate check. Host work only —
+    inside a jit trace it would time tracing, not execution."""
+    if not _ENABLED:
+        return _NULL_SPAN
+    from jax.profiler import TraceAnnotation
+
+    return _HostSpan(name, _key("span_s", dict(labels, span=name)),
+                     TraceAnnotation)
+
+
+def host_span_handle(name: str, **labels):
+    """Prebuilt :func:`host_span` for a per-call path: returns a zero-arg
+    factory of the span with the series key and annotation class bound
+    once, so a call pays no label sort, import or lock (the §12.5
+    budget). The factory re-checks the enabled flag on every call."""
+    from jax.profiler import TraceAnnotation
+
+    key = _key("span_s", dict(labels, span=name))
+
+    def handle():
+        if not _ENABLED:
+            return _NULL_SPAN
+        return _HostSpan(name, key, TraceAnnotation)
+    return handle

@@ -112,15 +112,19 @@ def pcg(matvec: Matvec, b: jnp.ndarray, *, M: Matvec | None = None,
 
     dot = dot or jnp.vdot
     norm = norm or jnp.linalg.norm
-    b, x0, bnorm, dtype = _prep(b, x0, dtype, norm)
     M = M or (lambda r: r)
-
-    r0 = b - matvec(x0).astype(dtype)
-    z0 = M(r0).astype(dtype)
-    rz0 = dot(r0, z0)
-    hist0 = jnp.full((maxiter + 1,), -1.0, dtype=jnp.float64 if
-                     dtype == jnp.float64 else jnp.float32)
-    hist0 = hist0.at[0].set(norm(r0) / bnorm)
+    # every op but the matvec sits under packsell.solver_vec: the dots,
+    # norms, axpys, the preconditioner apply and the history update
+    with _obs.span("packsell.solver_vec"):
+        b, x0, bnorm, dtype = _prep(b, x0, dtype, norm)
+    Ax0 = matvec(x0)
+    with _obs.span("packsell.solver_vec"):
+        r0 = b - Ax0.astype(dtype)
+        z0 = M(r0).astype(dtype)
+        rz0 = dot(r0, z0)
+        hist0 = jnp.full((maxiter + 1,), -1.0, dtype=jnp.float64 if
+                         dtype == jnp.float64 else jnp.float32)
+        hist0 = hist0.at[0].set(norm(r0) / bnorm)
 
     def cond(s):
         k, x, r, z, p, rz, hist, done = s
@@ -128,24 +132,27 @@ def pcg(matvec: Matvec, b: jnp.ndarray, *, M: Matvec | None = None,
 
     def body(s):
         k, x, r, z, p, rz, hist, done = s
-        Ap = matvec(p).astype(dtype)
-        pAp = dot(p, Ap)
-        alpha = rz / jnp.where(pAp == 0, 1.0, pAp)
-        x = x + alpha * p
-        r = r - alpha * Ap
-        relres = norm(r) / bnorm
-        hist = hist.at[k + 1].set(relres.astype(hist.dtype))
-        done = relres < tol
-        z = M(r).astype(dtype)
-        rz_new = dot(r, z)
-        beta = rz_new / jnp.where(rz == 0, 1.0, rz)
-        p = z + beta * p
-        return (k + 1, x, r, z, p, rz_new, hist, done)
+        Ap = matvec(p)
+        with _obs.span("packsell.solver_vec"):
+            Ap = Ap.astype(dtype)
+            pAp = dot(p, Ap)
+            alpha = rz / jnp.where(pAp == 0, 1.0, pAp)
+            x = x + alpha * p
+            r = r - alpha * Ap
+            relres = norm(r) / bnorm
+            hist = hist.at[k + 1].set(relres.astype(hist.dtype))
+            done = relres < tol
+            z = M(r).astype(dtype)
+            rz_new = dot(r, z)
+            beta = rz_new / jnp.where(rz == 0, 1.0, rz)
+            p = z + beta * p
+            return (k + 1, x, r, z, p, rz_new, hist, done)
 
     s0 = (jnp.asarray(0), x0, r0, z0, z0, rz0, hist0, jnp.asarray(False))
     with _obs.span("packsell.solver_while"):
         k, x, r, z, p, rz, hist, done = jax.lax.while_loop(cond, body, s0)
-    info = SolveInfo(k, norm(r) / bnorm, hist)
+    with _obs.span("packsell.solver_vec"):
+        info = SolveInfo(k, norm(r) / bnorm, hist)
     _obs.record_solve("pcg", info, path="eager")
     return x, info
 
@@ -233,7 +240,8 @@ def jacobi_pcg_stored(mat, plan, diag: jnp.ndarray, b: jnp.ndarray, *,
     fn = stored_solve_fn(plan, b, tol=tol, maxiter=maxiter, dtype=dtype)
     x0_s = jnp.zeros((plan.total_stored,),
                      dtype if dtype is not None else b.dtype)
-    x, info = fn(mat, plan._device_operands(), diag, b, x0_s)
+    with _obs.host_span("packsell.dispatch", kind="pcg"):
+        x, info = fn(mat, plan._device_operands(), diag, b, x0_s)
     _obs.record_solve("jacobi_pcg_stored", info, path="fused")
     return x, info
 
@@ -251,21 +259,25 @@ def stored_solve_fn(plan, b, *, tol: float, maxiter: int, dtype=None):
            sdtype.name)
     fn = plan._fns.get(key)
     if fn is None:
+        # the σ-permutes sit under packsell.stored_permute, never inside
+        # an SpMV scope or packsell.solver_vec
         def solve(mat_a, dev, diag_a, b_a, x0_s):
-            dinv = jnp.where(diag_a == 0, 1.0, 1.0 / diag_a)
-            dinv_s = _kp.stored_permute(dinv.astype(b_a.dtype),
-                                        dev["outrow"], plan.n)
-            b_s = _kp.stored_permute(b_a, dev["outrow"], plan.n)
+            with _obs.span("packsell.stored_permute"):
+                dinv = jnp.where(diag_a == 0, 1.0, 1.0 / diag_a)
+                dinv_s = _kp.stored_permute(dinv.astype(b_a.dtype),
+                                            dev["outrow"], plan.n)
+                b_s = _kp.stored_permute(b_a, dev["outrow"], plan.n)
 
             def matvec_s(x_s):
-                return plan.execute_with(
-                    mat_a, dev, _kp.stored_unpermute(x_s, dev["inv"]),
-                    permuted=True)
+                with _obs.span("packsell.stored_permute"):
+                    x = _kp.stored_unpermute(x_s, dev["inv"])
+                return plan.execute_with(mat_a, dev, x, permuted=True)
 
             x_s, info = pcg(matvec_s, b_s, M=lambda r: r * dinv_s,
                             tol=tol, maxiter=maxiter, dtype=dtype,
                             x0=x0_s)
-            return _kp.stored_unpermute(x_s, dev["inv"]), info
+            with _obs.span("packsell.stored_permute"):
+                return _kp.stored_unpermute(x_s, dev["inv"]), info
 
         fn = jax.jit(solve, donate_argnums=_donate(4))
         plan._fns[key] = fn

@@ -505,7 +505,7 @@ def test_distributed_shard_bodies_ride_fused(monkeypatch):
 
 def test_fused_kernel_span_and_dispatch_counter():
     from repro import observe
-    from repro.observe.profile import SPAN_NAMES
+    from repro.observe.metrics import SPAN_NAMES
     assert "packsell.fused_kernel" in SPAN_NAMES
     mat = packsell.from_csr(_int_csr(40, 50, 5, seed=31), C=8, sigma=32,
                             D=15, codec="fp16")

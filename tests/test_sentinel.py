@@ -1,5 +1,5 @@
-"""Perf sentinel (DESIGN.md §13): exporters, span profiling, and the
-noise-aware benchmark regression gate.
+"""Perf sentinel (DESIGN.md §13): exporters and the noise-aware
+benchmark regression gate.
 
 The load-bearing guarantees:
 
@@ -15,10 +15,7 @@ The load-bearing guarantees:
   is rejected with an error that says how to fix it;
 * **gate statistics** — the two-threshold design: single-class noise
   within severe_tol passes, correlated multi-class drift fails, and a
-  synthetic 2x slowdown on ONE class fails (the severe path);
-* **span profiling** — attribution on a real plan dispatch accounts for
-  the measured wall (or degrades to an explicit ``profiler_unavailable``
-  wallclock fallback when tracing is unavailable).
+  synthetic 2x slowdown on ONE class fails (the severe path).
 """
 from __future__ import annotations
 
@@ -329,89 +326,6 @@ def test_baseline_save_load_round_trip(tmp_path):
     p2.write_text(json.dumps(bad))
     with pytest.raises(trajectory.SchemaError, match="perf-baseline"):
         trajectory.load_baseline(str(p2))
-
-
-# ---------------------------------------------------------------------------
-# span profiling
-# ---------------------------------------------------------------------------
-
-
-def test_hlo_span_map_parses_scope_paths():
-    from repro.observe import profile
-    txt = (
-        'HloModule jit__execute, entry_computation_layout={()->f32[4]}\n'
-        '  %fusion.1 = f32[4] fusion(), metadata={op_name="jit(f)/'
-        'packsell.fused_decode/mul"}\n'
-        '  ROOT %gather.2 = f32[4] gather(), metadata={op_name="jit(f)/'
-        'packsell.gather_epilogue/gather"}\n'
-        '  %other.3 = f32[4] add(), metadata={op_name="jit(f)/plain/add"}\n'
-    )
-    m = profile.hlo_span_map(txt)
-    assert m[("jit__execute", "fusion.1")] == "packsell.fused_decode"
-    assert m[("jit__execute", "gather.2")] == "packsell.gather_epilogue"
-    assert ("jit__execute", "other.3") not in m
-
-
-def test_profile_dispatch_attributes_plan_spans(obs_on):
-    import jax
-    from repro.core import packsell as pk
-    from repro.core import testmats
-    from repro.kernels import plan as kplan
-    from repro.observe import profile
-
-    a = testmats.suite("tiny")["hpcg_mini"]
-    mat = pk.from_csr(a.tocsr(), C=32, sigma=256, D=15, codec="fp16")
-    plan = kplan.get_plan(mat)
-    import jax.numpy as jnp
-    x = jnp.asarray(np.random.default_rng(0)
-                    .standard_normal(mat.n).astype(np.float32))
-    fn = jax.jit(plan._execute, static_argnums=(3,))
-    txt = fn.lower(plan._exec_mat(mat), plan._device_operands(), x,
-                   False).compile().as_text()
-    prof = profile.profile_dispatch(
-        lambda v: plan.spmv(mat, v), x, hlo_texts=(txt,), repeats=5)
-    if prof.profiler_unavailable:
-        assert prof.mode == "wallclock" and prof.wall_s > 0
-        return
-    assert prof.mode == "trace"
-    # the acceptance figure: the breakdown explains >= 80% of the wall
-    assert prof.accounted_frac_of_wall >= 0.8
-    assert prof.attributed_frac >= 0.8
-    assert any(s["device_s"] > 0 for s in prof.spans.values())
-    d = prof.to_dict()
-    assert d["spans"] and d["wall_s"] > 0
-
-
-def test_profile_dispatch_fallback_marker(obs_on, monkeypatch):
-    import jax
-    from repro.observe import profile
-
-    def boom(*a, **k):
-        raise RuntimeError("no profiler here")
-
-    monkeypatch.setattr(jax.profiler, "trace", boom)
-    f = jax.jit(lambda x: x * 2.0)
-    prof = profile.profile_dispatch(f, np.float32(3.0), repeats=3)
-    assert prof.profiler_unavailable is True
-    assert prof.mode == "wallclock"
-    assert "trace failed" in prof.note
-    assert prof.wall_s > 0
-
-
-def test_profile_dispatch_raises_on_tpu(obs_on, monkeypatch):
-    """On a TPU a failed trace is an error: a wall clock is not a device
-    time."""
-    import jax
-    from repro.observe import profile
-
-    def boom(*a, **k):
-        raise RuntimeError("no profiler here")
-
-    monkeypatch.setattr(jax.profiler, "trace", boom)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    f = jax.jit(lambda x: x * 2.0)
-    with pytest.raises(RuntimeError, match="TPU trace failed"):
-        profile.profile_dispatch(f, np.float32(3.0), repeats=3)
 
 
 # ---------------------------------------------------------------------------

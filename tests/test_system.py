@@ -326,8 +326,11 @@ class TestLaunch:
     @pytest.fixture
     def cache_config(self):
         prev = jax.config.jax_compilation_cache_dir
+        keyed = jax.config.jax_compilation_cache_include_metadata_in_key
         yield
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          keyed)
 
     def test_compile_cache_defaults_to_checkout(self, cache_config,
                                                 monkeypatch):
@@ -337,6 +340,7 @@ class TestLaunch:
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         assert path == os.path.join(root, ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == path
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         with open(os.path.join(root, ".gitignore")) as f:
             assert ".jax_cache/" in f.read().split()
 
@@ -346,8 +350,9 @@ class TestLaunch:
         jax.config.update("jax_compilation_cache_dir", None)
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert cc.use_compile_cache() == str(tmp_path)
-        # JAX reads the variable itself: nothing else is set in code
+        # JAX reads the variable itself: no other directory is set in code
         assert jax.config.jax_compilation_cache_dir is None
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
 
     def test_peak_bandwidth_by_device_kind(self):
         from repro.launch import roofline as rl
