@@ -76,6 +76,7 @@ from repro.core import packsell as pk
 from repro.core.packsell import PackSELLMatrix
 from repro.observe import metrics as _obs
 from . import packsell_spmv as _pk
+from . import xgather as _xg
 
 _DEF_HW = 4096              # default half-window (elements, multiple of 128)
 _FULL_X_LIMIT = int(os.environ.get("REPRO_FULL_X_LIMIT", 2_000_000))
@@ -248,15 +249,32 @@ def _bucket_cursor_prefix(pack, d0, codec, D):
 # ---------------------------------------------------------------------------
 
 
-def _cursor_spmv(pack, cols, xc, codec, D):
-    """One bucket via the full cursor cache: value unpack + one gather +
-    one reduction — no runtime cumsum, but one int32 streamed per word."""
-    S, w, C = pack.shape
+def _sum_words(p):
+    """``p [S, w, C]`` summed over w in a fixed order: explicit add chains
+    over blocks of at most 32 words, the block sums chained the same way.
+    XLA takes a reduce's order from its operand's layout, which the form
+    of the x gather changes; the chain makes the windowed and the scalar
+    gather give the same bits."""
+    while p.shape[1] != 1:
+        S, w, C = p.shape
+        if w == 0:
+            return jnp.zeros((S, C), p.dtype)
+        k = min(w, 32)
+        q = jnp.pad(p, ((0, 0), (0, -w % k), (0, 0))).reshape(S, -1, k, C)
+        p = q[:, :, 0]
+        for j in range(1, k):
+            p = p + q[:, :, j]
+    return p[:, 0]
+
+
+def _cursor_spmv(pack, cols, xc, codec, D, xv=None):
+    """One bucket via the full cursor cache: value unpack + one x gather
+    (``xv``, the windowed gather's values, where the plan has them) +
+    one ordered reduction (:func:`_sum_words`) — no runtime cumsum."""
     v, _ = cd.unpack_words_jnp(pack, codec, D)
-    with _obs.span("packsell.x_gather"):
-        xv = jnp.take(xc, cols.reshape(-1), axis=0,
-                      mode="clip").reshape(S, w, C)
-    return jnp.sum(v.astype(jnp.float32) * xv, axis=1)
+    if xv is None:
+        xv = _take_x(xc, cols)
+    return _sum_words(v.astype(jnp.float32) * xv)
 
 
 def _cursor_spmm(pack, cols, xc, codec, D):
@@ -435,6 +453,18 @@ def _split16_encoding(mat: PackSELLMatrix):
     return None
 
 
+def _stream_encoding(split, locals_max: int, flag1_max: int, D: int):
+    """The fused stream's ``(encoding, scale)`` for its largest offset
+    ``locals_max`` (``flag1_max`` among value words), or None when no
+    compact encoding holds them; larger offsets never make one fit, so
+    the builder stops at the first bucket that overflows."""
+    if split is not None and locals_max < (1 << 16):
+        return split
+    if flag1_max < (1 << D) and locals_max < (1 << 31):
+        return "words", 0.0
+    return None
+
+
 def _build_fused_stream(mat: PackSELLMatrix, *, trim: bool = True,
                         wr: int | None = None):
     """Repack the bucketed words into the fused ragged-group layout, once,
@@ -485,6 +515,7 @@ def _build_fused_stream(mat: PackSELLMatrix, *, trim: bool = True,
     g0 = 0
     locals_max = 0
     flag1_max = 0
+    split = _split16_encoding(mat)
     for (used, _), pack, d0 in zip(used_w, mat.packs, mat.d0s):
         words = np.asarray(pack)
         S, w, C = words.shape
@@ -517,18 +548,15 @@ def _build_fused_stream(mat: PackSELLMatrix, *, trim: bool = True,
         locals_max = max(locals_max, int(lk.max(initial=0)))
         f1 = lk[(flag == 1) & keepw]
         flag1_max = max(flag1_max, int(f1.max(initial=0)))
+        enc = _stream_encoding(split, locals_max, flag1_max, D)
+        if enc is None:
+            return None, None, None     # span overflow: no compact encoding
         per_bucket.append((wp, flag, local, ck, S, maxr, levels))
         segs.append(FusedSegment(g0=g0, S=S, C=C, levels=levels))
         orders.append(order)
         g0 += int(sum(levels))
 
-    split = _split16_encoding(mat)
-    if split is not None and locals_max < (1 << 16):
-        encoding, scale = split
-    elif flag1_max < (1 << D) and locals_max < (1 << 31):
-        encoding, scale = "words", 0.0
-    else:
-        return None, None, None     # span overflow: no compact encoding
+    encoding, scale = _stream_encoding(split, locals_max, flag1_max, D)
 
     blk_w, blk_c = [], []
     for wp, flag, local, ck, S, maxr, levels in per_bucket:
@@ -612,18 +640,17 @@ def _fused_unpermute2(t2, inv2):
                                              unique_indices=True)
 
 
-def _fused_part_spmv(words3d, ckpt, xc, codec, D, layout: FusedLayout):
+def _fused_part_spmv(words3d, ckpt, xc, codec, D, layout: FusedLayout,
+                     xv=None):
     """The fused single-pass SpMV body (group partials [G, C]): one
-    decode over the whole stream, one checkpoint add, one clip-mode
-    gather, an unrolled accumulate over the group-width axis (an explicit
-    add chain — XLA fuses it into one pass where its reduce HLO would
-    not)."""
-    G, wr, C = words3d.shape
+    decode over the whole stream, one checkpoint add, one x gather
+    (``xv``, the windowed gather's values, where the plan has them), an
+    unrolled accumulate over the group-width axis (an explicit add chain
+    — XLA fuses it into one pass where its reduce HLO would not)."""
+    wr = words3d.shape[1]
     v, local = _fused_decode(words3d, codec, D, layout)
-    cols = ckpt[:, None, :] + local
-    with _obs.span("packsell.x_gather"):
-        xv = jnp.take(xc, cols.reshape(-1), axis=0,
-                      mode="clip").reshape(G, wr, C)
+    if xv is None:
+        xv = _take_x(xc, ckpt[:, None, :] + local)
     p = v * xv
     acc = p[:, 0, :]
     for j in range(1, wr):
@@ -650,6 +677,83 @@ def _fused_part_spmm(words3d, ckpt, xc, codec, D, layout: FusedLayout):
     if acc is None:
         acc = jnp.zeros((G, C, nb), jnp.float32)
     return acc
+
+
+# ---------------------------------------------------------------------------
+# Windowed x gather (DESIGN.md §10.5; host build and kernel in xgather.py)
+# ---------------------------------------------------------------------------
+
+
+def _fused_gather_columns(words3d, ckpt, mat: PackSELLMatrix,
+                          layout: FusedLayout):
+    """Host ``(cols, free)`` of the fused stream, both ``[G, wr, C]``:
+    the column the scalar gather reads for every word (checkpoint +
+    offset, clip-clamped to x as ``jnp.take(mode='clip')`` clamps it)
+    and whether the word's value is ±0, so its product is ±0 whatever
+    x entry it reads (padding, dummies)."""
+    w = np.asarray(words3d)
+    if layout.encoding == "words":
+        v, local, _ = cd.unpack_words_np(w, mat.codec, mat.D)
+        free = v == 0
+    else:
+        local = w & np.uint32(0xFFFF)
+        top = (np.uint32(0xFFFF0000) if layout.encoding == "fixed16"
+               else np.uint32(0x7FFF0000))       # ±0 in f16/top16
+        free = (w & top) == 0
+    cols = np.asarray(ckpt)[:, None, :].astype(np.int64) + local
+    return np.clip(cols, 0, max(mat.m - 1, 0)).astype(np.int32), free
+
+
+def _pin_empty_rows(free, layout: Optional[FusedLayout]):
+    """``free`` with the words of stored rows that hold no real word put
+    back: such a row's output is a sum of ±0 products, whose sign follows
+    the x entries read, so its words read exactly what the scalar gather
+    reads. ``layout`` maps the fused stream's groups to stored rows
+    (level-major segments); None is one cursor-cache bucket ``[S, w,
+    C]``, a slice per stored row."""
+    lane_free = free.all(axis=1)                       # [G, C]
+    if layout is None:
+        return free & ~lane_free[:, None, :]
+    pinned = np.zeros_like(lane_free)
+    for seg in layout.segments:
+        empty = np.ones((seg.S, seg.C), bool)
+        g = seg.g0
+        for Sk in seg.levels:
+            empty[:Sk] &= lane_free[g:g + Sk]
+            g += Sk
+        g = seg.g0
+        for Sk in seg.levels:
+            pinned[g:g + Sk] = empty[:Sk]
+            g += Sk
+    return free & ~pinned[:, None, :]
+
+
+def _plan_window(mat: PackSELLMatrix, fused, layout, cols):
+    """:func:`xgather.build_window` over the plan's x-gather columns — the
+    fused stream's, or each bucket's cursor cache (host arrays)."""
+    with _obs.host_span("packsell.plan_build.window"):
+        if fused is not None:
+            c, free = _fused_gather_columns(fused[0], fused[1], mat, layout)
+            parts = [(c, _pin_empty_rows(free, layout))]
+        elif cols is not None:
+            parts = []
+            for pack, c in zip(mat.packs, cols):
+                words = np.asarray(pack)
+                v, _, _ = cd.unpack_words_np(words.reshape(-1), mat.codec,
+                                             mat.D)
+                free = (v == 0).reshape(words.shape)
+                parts.append((np.asarray(c), _pin_empty_rows(free, None)))
+        else:
+            return 0, None
+        return _xg.build_window(parts, mat.m)
+
+
+def _take_x(xc, cols):
+    """``xc[cols]`` (clip-clamped): the scalar x gather of a decode
+    body."""
+    with _obs.span("packsell.x_gather"):
+        return jnp.take(xc, cols.reshape(-1), axis=0,
+                        mode="clip").reshape(cols.shape)
 
 
 def _build_block_checkpoints(mat: PackSELLMatrix, tiles):
@@ -728,6 +832,8 @@ class SpMVPlan:
     fused: Optional[tuple] = None     # (words2d uint32[R, wr], ckpt int32[R])
     fused_layout: Optional[FusedLayout] = None
     kckpts: Optional[tuple] = None    # per-bucket int32 [S, nw, C] (Pallas)
+    window: Optional[tuple] = None    # (wres, ((wbase, woff), ...)), §10.5
+    window_width: int = 0             # W of the windowed x gather; 0 = off
     total_words: int = 0              # bucketed words (decode-cache pricing)
     fused_trim: bool = True           # fused layout built with trimming?
     ephemeral: bool = False           # built under tracing: never cached/jitted
@@ -765,7 +871,8 @@ class SpMVPlan:
         if dev is None:
             dev = {"cols": self.cols, "inv": self.inv_cat,
                    "inv2": self.inv2_cat, "outrow": self.outrow_cat,
-                   "fused": self.fused, "kckpt": self.kckpts}
+                   "fused": self.fused, "kckpt": self.kckpts,
+                   "window": self.window}
             self._fns["_dev"] = dev
         return dev
 
@@ -793,8 +900,10 @@ class SpMVPlan:
                         interpret=self.interpret)
             else:
                 with _obs.span("packsell.fused_decode"):
-                    part = _fused_part_spmv(fused[0], fused[1], xc,
-                                            mat.codec, mat.D, lay)
+                    xv = self._window_gather(xc, dev, [fused[0].shape])
+                    part = _fused_part_spmv(
+                        fused[0], fused[1], xc, mat.codec, mat.D, lay,
+                        xv[0] if xv else None)
             return self._fused_epilogue(part, dev, permuted)
         if self.variant == "fused":
             raise ValueError("fused plan dispatched without its stream "
@@ -855,6 +964,9 @@ class SpMVPlan:
         """The per-bucket execution bodies (Pallas variants, the 'full'
         cursor cache, and the tracing scan fallback)."""
         kck = dev.get("kckpt")
+        xvs = None
+        if not multi_rhs and dev["cols"] is not None:
+            xvs = self._window_gather(xc, dev, [p.shape for p in mat.packs])
         parts = []
         for b, (pack, d0) in enumerate(zip(mat.packs, mat.d0s)):
             sb, wb = self.tiles[b]
@@ -887,7 +999,8 @@ class SpMVPlan:
                     pack, d0, x, codec_name=mat.codec_name, D=mat.D,
                     sb=sb, wb=wb, interpret=self.interpret, ckpt=ck)
             elif dev["cols"] is not None:
-                t = _cursor_spmv(pack, dev["cols"][b], xc, mat.codec, mat.D)
+                t = _cursor_spmv(pack, dev["cols"][b], xc, mat.codec, mat.D,
+                                 xvs[b] if xvs else None)
             else:
                 t = pk._bucket_spmv_scan(
                     pack, d0, xc, mat.codec, mat.D,
@@ -897,6 +1010,19 @@ class SpMVPlan:
             shape = (0, xc.shape[1]) if multi_rhs else (0,)
             return jnp.zeros(shape, jnp.float32)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+    def _window_gather(self, xc, dev: dict, shapes):
+        """x at every word of the parts shaped ``shapes`` (``[G, wr,
+        C]``) through the plan's windowed gather
+        (:func:`xgather.window_gather`), or None where ``dev`` has no
+        window operands (W = 0, stacked shard operands)."""
+        win = dev.get("window")
+        if win is None:
+            return None
+        with _obs.span("packsell.x_gather"):
+            return _xg.window_gather(xc, win, self.window_width,
+                                     [s[0] for s in shapes],
+                                     interpret=self.interpret)
 
     def _dispatch(self, kind: str):
         fn = self._fns.get(kind)
@@ -911,9 +1037,10 @@ class SpMVPlan:
                      multi_rhs: bool = False) -> jnp.ndarray:
         """Run the plan's execution body with externally supplied device
         operands (``{'cols': tuple|None, 'inv': array|None, 'outrow':
-        array, 'fused': (words2d, ckpt)|None, 'kckpt': tuple|None}``;
-        missing keys are treated as None) inside an existing trace — the
-        shard_map reuse hook.
+        array, 'fused': (words2d, ckpt)|None, 'kckpt': tuple|None,
+        'window': (wres, ((wbase, woff), ...))|None}``; missing keys are
+        treated as None, and without ``'window'`` the decode gathers x with one scalar
+        gather) inside an existing trace — the shard_map reuse hook.
 
         The distributed layer builds one concrete plan per shard, stacks
         the per-shard operands along the mesh axis, and calls this inside
@@ -953,7 +1080,10 @@ class SpMVPlan:
         rebuilds its stream), never per call: the 32-bit words the
         executed decode streams per call (the fused stream on the
         checkpoint path, the bucketed packs otherwise), the int32
-        cursor-cache or checkpoint words it reads, dummies and nnz."""
+        cursor-cache or checkpoint words it reads, dummies and nnz; and
+        the windowed x gather's width W (0 when off), its window indices
+        per call (one per (group, word) pair) and its residual words (the
+        indices left to the scalar gather)."""
         dcs = self.decode_cache_stats()
         on_stream = (self.fused is not None
                      and self.variant in ("jnp", "fused"))
@@ -966,6 +1096,16 @@ class SpMVPlan:
                    **lab)
         _obs.gauge("plan.dummies", int(mat.n_dummy), **lab)
         _obs.gauge("plan.nnz", int(mat.nnz), **lab)
+        if self.window is None:
+            pairs = resid = 0
+        else:
+            shapes = ([self.fused[0].shape] if self.fused is not None
+                      else [p.shape for p in mat.packs])
+            pairs = sum(int(s[0] * s[1]) for s in shapes)
+            resid = int(self.window[0].shape[0])
+        _obs.gauge("plan.window_width", int(self.window_width), **lab)
+        _obs.gauge("plan.window_pairs", pairs, **lab)
+        _obs.gauge("plan.residual_words", resid, **lab)
 
     def _obs_handles(self, mat: PackSELLMatrix, kind: str):
         """``(span, bump)`` for one recorded dispatch (DESIGN.md §12): the
@@ -1084,7 +1224,8 @@ class SpMVPlan:
                 "cursor_cache": self.cols is not None,
                 "fused": self.fused is not None,
                 "ckpt_width": (None if self.fused_layout is None
-                               else self.fused_layout.wr)}
+                               else self.fused_layout.wr),
+                "window_width": self.window_width}
 
     def decode_cache_stats(self) -> dict:
         """Decode-cache device memory, priced against the PR-1 full cursor
@@ -1166,10 +1307,16 @@ class SpMVPlan:
                     "span overflows every compact offset encoding)")
             self.fused_layout = layout
             # the slice sort depends on runs-per-slice = f(wr): re-bake the
-            # stored order and both inverse-permutation forms
+            # stored order and both inverse-permutation forms, and the
+            # window operands follow the new stream
             outrow, inv, inv2 = _stored_order(mat, orders, True)
-            (self.fused, self.outrow_cat, self.inv_cat,
-             self.inv2_cat) = jax.device_put((fused, outrow, inv, inv2))
+            W, window = (_plan_window(mat, fused, layout, None)
+                         if self.variant == "jnp" and self.fused_trim
+                         else (0, None))
+            (self.fused, self.outrow_cat, self.inv_cat, self.inv2_cat,
+             self.window) = jax.device_put((fused, outrow, inv, inv2,
+                                            window))
+            self.window_width = W
             self.tiles = tiles
             self._fns.clear()
             _quick_validate(mat, self)
@@ -1344,14 +1491,20 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
     elif mode == "checkpoint":
         with _obs.host_span("packsell.plan_build.cache"):
             kckpts = _build_block_checkpoints(mat, tiles)
+    W, window = 0, None
+    if variant == "jnp" and fused_trim:
+        # the jnp decode bodies gather x through windows where the
+        # matrix's spans make that cheaper; an untrimmed (SPMD-stacked)
+        # plan keeps the scalar gather, its layout being shape-derived
+        W, window = _plan_window(mat, fused, layout, cols)
     with _obs.host_span("packsell.plan_build.inverse"):
         # the fused layout's per-bucket slice sort is baked into the
         # stored order (outputs of the fused tail land in sorted order)
         outrow_cat, inv, inv2 = _stored_order(mat, orders,
                                               fused is not None)
     with _obs.host_span("packsell.plan_build.to_device"):
-        fused, cols, kckpts, outrow_cat, inv, inv2 = jax.device_put(
-            (fused, cols, kckpts, outrow_cat, inv, inv2))
+        fused, cols, kckpts, outrow_cat, inv, inv2, window = jax.device_put(
+            (fused, cols, kckpts, outrow_cat, inv, inv2, window))
     plan = SpMVPlan(
         variant=variant, policy=f"{variant} ({reason})", hw=hw,
         interpret=interpret, tiles=tiles,
@@ -1361,7 +1514,7 @@ def _build_plan(mat: PackSELLMatrix, *, sb: int = 8, wb: int = 32,
                          for p in mat.packs),
         inv_cat=inv, inv2_cat=inv2,
         cols=cols, cache_mode=mode, fused=fused, fused_layout=layout,
-        kckpts=kckpts,
+        kckpts=kckpts, window=window, window_width=W,
         total_words=sum(int(np.prod(p.shape)) for p in mat.packs),
         fused_trim=fused_trim,
         _matref=weakref.ref(mat))

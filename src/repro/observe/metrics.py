@@ -69,6 +69,7 @@ _HOST_SPAN_NAMES = (
     "packsell.plan_build.stream",
     "packsell.plan_build.cache",
     "packsell.plan_build.inverse",
+    "packsell.plan_build.window",
     "packsell.plan_build.to_device",
     "packsell.dispatch",
 )

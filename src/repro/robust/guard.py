@@ -118,7 +118,9 @@ def _checksum_jnp(arrays):
     for a in arrays:
         if a is None or a.size == 0:
             continue
-        if a.dtype != jnp.uint32:
+        if a.dtype.itemsize < 4:
+            a = a.astype(jnp.uint32)        # widened, as _as_u32_np does
+        elif a.dtype != jnp.uint32:
             a = jax.lax.bitcast_convert_type(a, jnp.uint32)
         u = a.ravel()
         s0 = s0 + jnp.sum(u, dtype=jnp.uint32)
@@ -131,7 +133,8 @@ def guard_arrays(mat: PackSELLMatrix, plan) -> list:
     """Every operand array the plan's execution path actually reads — the
     checksum coverage set (and the injection surface of
     ``robust.inject``). Fused 'jnp' plans stream the repacked words, not
-    the bucketed packs, so only the former is covered."""
+    the bucketed packs, so only the former is covered; a plan with a
+    windowed x gather adds its window operands."""
     dev = plan._device_operands()
     arrs = []
     if dev.get("fused") is not None and plan.variant == "jnp":
@@ -142,6 +145,7 @@ def guard_arrays(mat: PackSELLMatrix, plan) -> list:
             arrs += list(dev["cols"])
         if dev.get("kckpt") is not None:
             arrs += list(dev["kckpt"])
+    arrs += jax.tree.leaves(dev.get("window"))
     if dev.get("inv2") is not None:
         arrs.append(dev["inv2"])
     elif dev.get("inv") is not None:
@@ -385,6 +389,7 @@ def _guard_arrays_traced(matv, dev, plan):
             arrs += list(dev["cols"])
         if dev.get("kckpt") is not None:
             arrs += list(dev["kckpt"])
+    arrs += jax.tree.leaves(dev.get("window"))
     if dev.get("inv2") is not None:
         arrs.append(dev["inv2"])
     elif dev.get("inv") is not None:

@@ -5,6 +5,8 @@ the HPCG default local grid (104^3, fp16/D15) for one chip of a described
 ``v5e:2x2`` topology; nothing runs. The Pallas bodies are compiled too, and
 the test pins what Mosaic refuses today: that refusal is why ``auto``
 selects the XLA ``jnp`` path on a TPU (``kernels.plan.PALLAS_REFUSAL``).
+The one Mosaic kernel of that path, the windowed x gather's lane pick,
+compiles inside the dispatch and the solve.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library, and the test workers all import
@@ -22,6 +24,7 @@ from repro.core import packsell, testmats
 from repro.kernels import packsell_spmv as pk
 from repro.kernels import plan as kplan
 from repro.kernels import sell_spmv as sk
+from repro.kernels import xgather as xg
 from repro.solvers import cg
 from repro.solvers import operators as op
 
@@ -88,7 +91,12 @@ def test_spmv_dispatch_compiles_at_hpcg_104(hpcg_plan, one_chip):
         _shapes(plan._exec_mat(mat), one_chip),
         _shapes(plan._device_operands(), one_chip), x, False).compile()
     _fits_one_chip(compiled)
-    assert "tpu_custom_call" not in compiled.as_text()   # no Pallas body
+    # no Pallas SpMV body: the one Mosaic kernel is the windowed x
+    # gather's lane pick, which the plan's cost model engages at 104^3
+    assert plan.window_width > 0
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and xg.PICK_KERNEL in calls[0]
 
 
 def test_jacobi_pcg_stored_compiles_at_hpcg_104(hpcg_plan, one_chip):
