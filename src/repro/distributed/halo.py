@@ -99,25 +99,29 @@ def gather_halo(x_loc: jnp.ndarray, dev: dict, *, axis_name: str,
     """Device-side exchange (runs inside a shard_map body). ``x_loc`` is
     this shard's ``[n_pad]`` (or ``[n_pad, nb]``) x-block; ``dev`` holds this
     shard's slices of the stacked maps. Returns ``x_halo`` ``[h_pad(, nb)]``.
+    The whole exchange (the send takes, the ``ppermute`` rounds and the
+    receive scatters, or the ``all_gather`` and its take) sits under the
+    device scope ``packsell.halo``.
     """
     out_shape = (h_pad,) + tuple(x_loc.shape[1:])
     if h_pad == 0:
         return jnp.zeros(out_shape, x_loc.dtype)
-    if mode == "all_gather":
-        x_full = jax.lax.all_gather(x_loc, axis_name)        # [P, n_pad(,nb)]
-        x_full = x_full.reshape((-1,) + tuple(x_loc.shape[1:]))
-        return jnp.take(x_full, dev["halo_src"][:h_pad], axis=0)
-    if mode != "ppermute":
+    if mode not in EXCHANGE_MODES:
         raise ValueError(f"mode={mode!r} not in {EXCHANGE_MODES}")
-    x_halo = jnp.zeros(out_shape, x_loc.dtype)
-    for s in range(1, n_shards):
-        buf = jnp.take(x_loc, dev["send_idx"][s - 1], axis=0)
-        buf = jax.lax.ppermute(
-            buf, axis_name,
-            perm=[(p, (p + s) % n_shards) for p in range(n_shards)])
-        # pad entries carry recv_slot == h_pad -> dropped (out of bounds)
-        x_halo = x_halo.at[dev["recv_slot"][s - 1]].set(buf, mode="drop")
-    return x_halo
+    with _obs.span("packsell.halo"):
+        if mode == "all_gather":
+            x_full = jax.lax.all_gather(x_loc, axis_name)  # [P, n_pad(,nb)]
+            x_full = x_full.reshape((-1,) + tuple(x_loc.shape[1:]))
+            return jnp.take(x_full, dev["halo_src"][:h_pad], axis=0)
+        x_halo = jnp.zeros(out_shape, x_loc.dtype)
+        for s in range(1, n_shards):
+            buf = jnp.take(x_loc, dev["send_idx"][s - 1], axis=0)
+            buf = jax.lax.ppermute(
+                buf, axis_name,
+                perm=[(p, (p + s) % n_shards) for p in range(n_shards)])
+            # pad entries carry recv_slot == h_pad -> dropped (out of bounds)
+            x_halo = x_halo.at[dev["recv_slot"][s - 1]].set(buf, mode="drop")
+        return x_halo
 
 
 def prestage(shared: dict, *, axis_name: str, n_shards: int, h_pad: int,
@@ -130,14 +134,16 @@ def prestage(shared: dict, *, axis_name: str, n_shards: int, h_pad: int,
     Hoisting the exchange into a pre-stage is what lets the distributed
     tier ladder (``cg.adaptive_pcg_dist``) run ONE collective per matvec
     outside the tier ``lax.switch``: every tier shares the same index
-    maps, so the gathered buffer feeds whichever tier is active.
+    maps, so the gathered buffer feeds whichever tier is active. The
+    exchange is :func:`gather_halo`, so it carries that function's
+    ``packsell.halo`` scope, as the halo of every other distributed
+    matvec does.
     """
     def pre(x_loc: jnp.ndarray) -> tuple:
         if h_pad == 0:
             return ()
-        with _obs.span("packsell.halo_prestage"):
-            return (gather_halo(x_loc, shared, axis_name=axis_name,
-                                n_shards=n_shards, h_pad=h_pad, mode=mode),)
+        return (gather_halo(x_loc, shared, axis_name=axis_name,
+                            n_shards=n_shards, h_pad=h_pad, mode=mode),)
     return pre
 
 

@@ -33,6 +33,10 @@ mixed-precision** compose: ``dist_mixed:<budget>`` operators and
 * :func:`build_dist_tiers` stacks one member set per codec tier over ONE
   shared partition — the distributed tier ladder ``adaptive_pcg_dist``
   promotes through via ``lax.switch``.
+* Both builders run under the host span ``packsell.dist_build`` (stages
+  ``.partition``, ``.members``, ``.stack``, ``.to_device``); a
+  ``DistSpMVPlan`` sets the gauges ``dist.shards``, ``dist.halo_entries``,
+  ``dist.h_pad`` and ``dist.remote_nnz`` when it is built.
 
 ``reference_spmv`` replays the exact same stacked operands shard-by-shard
 on the host (no mesh, no collectives) — the oracle that lets partition and
@@ -324,11 +328,12 @@ class _PartitionCtx:
 
 def _partition_context(a: sp.csr_matrix, n_shards: int,
                        C: int) -> _PartitionCtx:
-    part = dp.partition_rows(a.shape[0], n_shards)
-    n_pad = _ceil_to(max(int(part.counts.max(initial=0)), 1), C)
-    splits, h_pad = dp.split_csr(a, part, n_pad=n_pad)
-    maps = dh.build_halo_maps(part, [s.halo_cols for s in splits],
-                              n_pad=n_pad, h_pad=h_pad)
+    with _obs.host_span("packsell.dist_build.partition"):
+        part = dp.partition_rows(a.shape[0], n_shards)
+        n_pad = _ceil_to(max(int(part.counts.max(initial=0)), 1), C)
+        splits, h_pad = dp.split_csr(a, part, n_pad=n_pad)
+        maps = dh.build_halo_maps(part, [s.halo_cols for s in splits],
+                                  n_pad=n_pad, h_pad=h_pad)
     return _PartitionCtx(part=part, splits=splits, maps=maps, n_pad=n_pad,
                          h_pad=h_pad)
 
@@ -371,45 +376,45 @@ def build_composite_operands(a: sp.csr_matrix, n_shards: int, *,
     }
     members: list[DistMember] = []
     sides = [("loc", 0, 0)] + ([("rem", 1, 1)] if h_pad > 0 else [])
-    for side, term, x_index in sides:
-        for codec, D, rows in norm:
-            mask = np.ones(n, bool) if rows is None else \
-                np.zeros(n, bool)
-            if rows is not None:
-                mask[rows] = True
-            blocks, rows_local = [], []
-            for p in range(part.n_shards):
-                r0, r1 = part.rows_of(p)
-                src = (splits[p].a_loc if side == "loc"
-                       else splits[p].a_rem)
-                if rows is None:
-                    # all-rows class: the split block IS the member block
-                    # (identity row map; no CSR fancy-index copy)
-                    blocks.append(src)
-                    rows_local.append(None)
-                else:
-                    rl = np.nonzero(mask[r0:r1])[0].astype(np.int64)
-                    blocks.append(src[rl])
-                    rows_local.append(rl)
-            members.append(_build_dist_member(
-                len(members), blocks, rows_local, codec, D, C=C,
-                sigma=sigma, term=term, x_index=x_index,
-                label=f"{side}:{codec}" + ("" if codec in kc.SELL_CODECS
-                                           else f"/D={D}")))
-    for dm in members:
-        host.update(dm.host_arrays())
-
-    n_terms = 1 + (1 if h_pad > 0 else 0)
-    for t in range(n_terms):
-        tms = [dm for dm in members if dm.term == t]
-        host[f"inv{t}"] = np.stack([
-            kc.term_inverse(n_pad, [dm.shard_member(p) for dm in tms],
-                            allow_uncovered=True, term=t)
-            for p in range(part.n_shards)])
-
-    tpl = kc.CompositePlan([dm.shard_member(0) for dm in members],
-                           n=n_pad, m=n_pad, allow_uncovered=True,
-                           name="dist")
+    with _obs.host_span("packsell.dist_build.members"):
+        for side, term, x_index in sides:
+            for codec, D, rows in norm:
+                mask = np.ones(n, bool) if rows is None else \
+                    np.zeros(n, bool)
+                if rows is not None:
+                    mask[rows] = True
+                blocks, rows_local = [], []
+                for p in range(part.n_shards):
+                    r0, r1 = part.rows_of(p)
+                    src = (splits[p].a_loc if side == "loc"
+                           else splits[p].a_rem)
+                    if rows is None:
+                        # all-rows class: the split block IS the member
+                        # block (identity row map; no CSR fancy-index copy)
+                        blocks.append(src)
+                        rows_local.append(None)
+                    else:
+                        rl = np.nonzero(mask[r0:r1])[0].astype(np.int64)
+                        blocks.append(src[rl])
+                        rows_local.append(rl)
+                members.append(_build_dist_member(
+                    len(members), blocks, rows_local, codec, D, C=C,
+                    sigma=sigma, term=term, x_index=x_index,
+                    label=f"{side}:{codec}" + (
+                        "" if codec in kc.SELL_CODECS else f"/D={D}")))
+    with _obs.host_span("packsell.dist_build.stack"):
+        for dm in members:
+            host.update(dm.host_arrays())
+        n_terms = 1 + (1 if h_pad > 0 else 0)
+        for t in range(n_terms):
+            tms = [dm for dm in members if dm.term == t]
+            host[f"inv{t}"] = np.stack([
+                kc.term_inverse(n_pad, [dm.shard_member(p) for dm in tms],
+                                allow_uncovered=True, term=t)
+                for p in range(part.n_shards)])
+        tpl = kc.CompositePlan([dm.shard_member(0) for dm in members],
+                               n=n_pad, m=n_pad, allow_uncovered=True,
+                               name="dist")
     codec0, D0 = ((norm[0][0], norm[0][1]) if len(norm) == 1
                   else ("mixed", 0))
     return DistOperands(part=part, maps=maps, n=n, n_pad=n_pad, h_pad=h_pad,
@@ -462,8 +467,9 @@ class _MeshBound:
         self.mesh = mesh
         self.axis_name = mesh.axis_names[0]
         shard = NamedSharding(mesh, P(self.axis_name))
-        self.dev = jax.tree.map(
-            lambda v: jax.device_put(v, shard), host)
+        with _obs.host_span("packsell.dist_build.to_device"):
+            self.dev = jax.tree.map(
+                lambda v: jax.device_put(v, shard), host)
         self._fns: dict = {}
 
     @property
@@ -538,6 +544,20 @@ class DistSpMVPlan(_MeshBound):
         self.ops = ops
         self.exchange = exchange
         self._bind(ops, mesh, ops.host)
+        self._gauge_counts()
+
+    def _gauge_counts(self) -> None:
+        """Gauges set once when the plan is built: the shards, the halo
+        entries the exchange fills per matvec (summed over shards,
+        unpadded), the padded halo buffer length and the nonzeros of the
+        remote members (those that read the halo)."""
+        ops = self.ops
+        _obs.gauge("dist.shards", self.n_shards)
+        _obs.gauge("dist.halo_entries", int(ops.maps.counts.sum()))
+        _obs.gauge("dist.h_pad", int(ops.h_pad))
+        _obs.gauge("dist.remote_nnz", sum(
+            int(m.nnz) for dm in ops.members if dm.x_index == 1
+            for m in dm.mats))
 
     def _spmv_fn(self, mode: str, multi_rhs: bool):
         def build():
@@ -642,9 +662,10 @@ def build_dist_plan(a: sp.csr_matrix, n_shards: int | None = None, *,
         classes = [(c.codec, c.D, c.rows) for c in pplan.classes]
     if classes is None:
         classes = [(codec, D, None)]
-    ops = build_composite_operands(a, int(mesh.devices.size),
-                                   classes=classes, C=C, sigma=sigma)
-    return DistSpMVPlan(ops, mesh, exchange=exchange)
+    with _obs.host_span("packsell.dist_build"):
+        ops = build_composite_operands(a, int(mesh.devices.size),
+                                       classes=classes, C=C, sigma=sigma)
+        return DistSpMVPlan(ops, mesh, exchange=exchange)
 
 
 # ---------------------------------------------------------------------------
@@ -707,15 +728,16 @@ def build_dist_tiers(a: sp.csr_matrix, ladder, *, mesh=None,
                                devices=devices)
     ncls = _normalize_classes(ladder)
     a = a.tocsr()
-    ctx = _partition_context(a, int(mesh.devices.size), C)
-    tiers_ops = [build_composite_operands(
-        a, int(mesh.devices.size), classes=[(codec, D, None)],
-        C=C, sigma=sigma, ctx=ctx) for codec, D, _ in ncls]
-    hi_ops = build_composite_operands(
-        a, int(mesh.devices.size), classes=[("fp64", 0, None)],
-        C=C, sigma=sigma, ctx=ctx)
-    labels = [codec if codec in kc.SELL_CODECS else f"{codec}/D={D}"
-              for codec, D, _ in ncls]
-    sub32 = [codec not in kc.SELL_CODECS for codec, D, _ in ncls]
-    return DistTierLadder(tiers_ops, hi_ops, mesh, labels=labels,
-                          sub32=sub32, exchange=exchange)
+    with _obs.host_span("packsell.dist_build"):
+        ctx = _partition_context(a, int(mesh.devices.size), C)
+        tiers_ops = [build_composite_operands(
+            a, int(mesh.devices.size), classes=[(codec, D, None)],
+            C=C, sigma=sigma, ctx=ctx) for codec, D, _ in ncls]
+        hi_ops = build_composite_operands(
+            a, int(mesh.devices.size), classes=[("fp64", 0, None)],
+            C=C, sigma=sigma, ctx=ctx)
+        labels = [codec if codec in kc.SELL_CODECS else f"{codec}/D={D}"
+                  for codec, D, _ in ncls]
+        sub32 = [codec not in kc.SELL_CODECS for codec, D, _ in ncls]
+        return DistTierLadder(tiers_ops, hi_ops, mesh, labels=labels,
+                              sub32=sub32, exchange=exchange)

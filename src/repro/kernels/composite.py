@@ -400,18 +400,22 @@ class CompositePlan:
             t = mem.execute(mat, dev, xs[mem.x_index], multi_rhs=multi_rhs)
             parts[mem.term].append(t)
         y = None
-        for term_parts, inv in zip(parts, invs):
-            t_cat = (term_parts[0] if len(term_parts) == 1
-                     else jnp.concatenate(term_parts))
-            if self.pad_slot:
-                pad = jnp.zeros((1,) + tuple(t_cat.shape[1:]), t_cat.dtype)
-                t_cat = jnp.concatenate([t_cat, pad])
-            # each covered row has exactly one term slot (unique indices;
-            # with a pad slot the uncovered rows share it, so the hint is
-            # only safe without one)
-            yt = jnp.take(t_cat, inv, axis=0, mode="clip",
-                          unique_indices=not self.pad_slot)
-            y = yt if y is None else y + yt
+        # each term's one inverse-permutation gather is the composite's
+        # epilogue, named as a single plan's is
+        with _obs.span("packsell.gather_epilogue"):
+            for term_parts, inv in zip(parts, invs):
+                t_cat = (term_parts[0] if len(term_parts) == 1
+                         else jnp.concatenate(term_parts))
+                if self.pad_slot:
+                    pad = jnp.zeros((1,) + tuple(t_cat.shape[1:]),
+                                    t_cat.dtype)
+                    t_cat = jnp.concatenate([t_cat, pad])
+                # each covered row has exactly one term slot (unique
+                # indices; with a pad slot the uncovered rows share it, so
+                # the hint is only safe without one)
+                yt = jnp.take(t_cat, inv, axis=0, mode="clip",
+                              unique_indices=not self.pad_slot)
+                y = yt if y is None else y + yt
         return y
 
     def execute_with(self, mats, devs, invs, xs, *,

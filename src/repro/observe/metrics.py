@@ -44,14 +44,17 @@ __all__ = [
 #: The program's span and scope names (DESIGN.md §12.2). Device scopes,
 #: planted by :func:`span` as ``named_scope`` metadata on the ops they
 #: enclose (``x_gather`` nests inside the decode scopes; the solver's
-#: vector work and its σ-permutes never nest inside an SpMV scope):
+#: vector work and its σ-permutes never nest inside an SpMV scope; the
+#: distributed solve's ``allreduce`` nests inside ``solver_vec`` and its
+#: ``halo`` exchange inside none):
 _SCOPE_NAMES = (
     "packsell.fused_decode",
     "packsell.fused_kernel",
     "packsell.bucket_decode",
     "packsell.x_gather",
     "packsell.gather_epilogue",
-    "packsell.halo_prestage",
+    "packsell.halo",
+    "packsell.allreduce",
     "packsell.guard_checksum",
     "packsell.solver_while",
     "packsell.solver_vec",
@@ -72,6 +75,11 @@ _HOST_SPAN_NAMES = (
     "packsell.plan_build.window",
     "packsell.plan_build.to_device",
     "packsell.dispatch",
+    "packsell.dist_build",
+    "packsell.dist_build.partition",
+    "packsell.dist_build.members",
+    "packsell.dist_build.stack",
+    "packsell.dist_build.to_device",
 )
 SPAN_NAMES = _SCOPE_NAMES + _HOST_SPAN_NAMES
 

@@ -56,12 +56,12 @@ def dist_dot(axis_name: str):
     ``psum`` so every shard holds the identical global scalar (vectors are
     real; σ/shard padding slots must be zero — the distributed layer's
     row-mask invariant guarantees it for its vectors)."""
-    return lambda a, b: jax.lax.psum(jnp.vdot(a, b), axis_name)
+    return lambda a, b: _allreduce(jnp.vdot(a, b), axis_name)
 
 
 def dist_norm(axis_name: str):
     """‖a‖₂ over a device mesh axis (psum of local squared sums)."""
-    return lambda a: jnp.sqrt(jax.lax.psum(jnp.sum(a * a), axis_name))
+    return lambda a: jnp.sqrt(_allreduce(jnp.sum(a * a), axis_name))
 
 
 def _prep(b, x0, dtype, norm):
@@ -284,6 +284,14 @@ def stored_solve_fn(plan, b, *, tol: float, maxiter: int, dtype=None):
     return fn
 
 
+def _allreduce(v: jnp.ndarray, axis_name: str) -> jnp.ndarray:
+    """``psum`` of a local partial under the device scope
+    ``packsell.allreduce`` (inside ``pcg`` it nests under
+    ``packsell.solver_vec``, as the dots and norms do)."""
+    with _obs.span("packsell.allreduce"):
+        return jax.lax.psum(v, axis_name)
+
+
 def jacobi_pcg_dist(dplan, diag: jnp.ndarray, b: jnp.ndarray, *,
                     tol: float = 1e-9, maxiter: int = 1000,
                     dtype=None, mode: str | None = None
@@ -293,12 +301,14 @@ def jacobi_pcg_dist(dplan, diag: jnp.ndarray, b: jnp.ndarray, *,
 
     ``dplan`` is a :class:`~repro.distributed.plan.DistSpMVPlan`; each
     iteration's matvec is the per-shard halo-exchange SpMV body (local
-    block overlapping the exchange, remote block on the gathered halo), and
-    every dot/norm is psum-reduced (:func:`dist_dot` / :func:`dist_norm`) so
-    all shards advance through the identical scalar recurrence — the
-    iteration count matches the single-device solver up to summation-order
-    rounding. Vectors stay sharded for the whole solve; only the final x
-    (and the replicated scalars/history) come back to the host.
+    block overlapping the exchange, remote block on the gathered halo; the
+    exchange sits under the device scope ``packsell.halo``), and every
+    dot/norm is psum-reduced under ``packsell.allreduce`` (:func:`dist_dot`
+    / :func:`dist_norm`) so all shards advance through the identical
+    scalar recurrence — the iteration count matches the single-device
+    solver up to summation-order rounding. Vectors stay sharded for the
+    whole solve; only the final x (and the replicated scalars/history)
+    come back to the host.
 
     ``diag``: matrix diagonal in global row order (the Jacobi
     preconditioner); ``b``: global right-hand side; ``mode`` overrides the
